@@ -38,6 +38,9 @@ def main() -> None:
     ap.add_argument("--json", action="store_true",
                     help="also write rows to BENCH_<platform>.json")
     args, _ = ap.parse_known_args()
+    from repro.launch.compile_cache import use_compile_cache
+
+    use_compile_cache()
     print("name,us_per_call,derived")
     failures = 0
     all_rows = []

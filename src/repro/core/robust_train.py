@@ -572,14 +572,6 @@ def make_dynabro_scan_fn(grad_fn: GradFn, cfg: DynaBROConfig, opt: Optimizer,
                 "microbatch streaming is not supported on the sweep "
                 "variants (DESIGN.md §9); drop sweep_mesh/microbatch")
         _check_lane_mesh(sweep_mesh, lane_axis, worker_axis)
-        if math.prod(list(sweep_mesh.shape.values())) > 1:
-            # same backend freeze as the 1-axis mesh path: the sweep runs
-            # the segment inside a shard_map region, where interpret-mode
-            # pallas cannot lower (the 1-device mesh skips the shard_map
-            # and so keeps dynamic dispatch — bitwise with the unsharded
-            # sweep by construction)
-            cfg = dataclasses.replace(
-                cfg, agg_backend=agg_engine.resolve_backend(cfg.agg_backend))
     if microbatch and (lane_attacks is not None
                        or lane_aggregators is not None):
         raise ValueError(
@@ -590,19 +582,18 @@ def make_dynabro_scan_fn(grad_fn: GradFn, cfg: DynaBROConfig, opt: Optimizer,
         raise ValueError(
             "param_specs only applies to the 2-axis (workers, 'model') GSPMD "
             "path; the 1-axis shard_map path replicates params (DESIGN.md §9)")
-    if mesh is not None:
-        # inside the manual shard_map region the size dispatch must never
-        # pick an interpret-mode pallas kernel (the legacy lowering cannot
-        # host a pallas_call there) — freeze 'auto' at build time to its
-        # pre-dispatch meaning: pallas on TPU, ref elsewhere
-        cfg = dataclasses.replace(
-            cfg, agg_backend=agg_engine.resolve_backend(cfg.agg_backend))
     j_max = cfg.mlmc.j_max
     n_max = 2 ** j_max if cfg.use_mlmc else 1
     gather = None if gspmd else _worker_gather(
         mesh if mesh is not None else sweep_mesh, worker_axis)
     constrain = _gspmd_constraints(mesh, worker_axis, param_specs) \
         if gspmd else None
+    if (constrain is not None and cfg.agg_backend == "auto"
+            and mesh.devices.flat[0].platform == "tpu"):
+        # GSPMD partitions this path's aggregation itself, and a compiled
+        # (Mosaic) kernel cannot be partitioned automatically: no manual
+        # region hosts it here, so the rules run on the XLA reference
+        cfg = dataclasses.replace(cfg, agg_backend="ref")
     atk_one = (attacks_lib.get_attack(cfg.attack, **(cfg.attack_kwargs or {}))
                if microbatch else None)
     atk_apply = (attacks_lib.attack_switch(tuple(lane_attacks))
@@ -769,10 +760,8 @@ def _shard_seg(seg, mesh, worker_axis: str, xs_batch_axes):
     """
     from jax.sharding import PartitionSpec as P
 
-    from repro.launch.mesh import shard_map
-
     xs_specs = tuple(P(None) if a is None else P(None, a) for a in xs_batch_axes)
-    return shard_map(
+    return jax.shard_map(
         seg, mesh=mesh,
         in_specs=(P(), xs_specs),
         out_specs=(P(), P(None)),
@@ -932,11 +921,6 @@ def make_momentum_scan_fn(grad_fn: GradFn, cfg: DynaBROConfig, lr: float,
             "momentum scan driver supports only 1-axis worker meshes; the "
             "2-axis (workers, 'model') GSPMD path is DynaBRO-only "
             "(DESIGN.md §9)")
-    if mesh is not None:
-        # same backend freeze as make_dynabro_scan_fn: no interpret-mode
-        # pallas inside the manual shard_map region
-        cfg = dataclasses.replace(
-            cfg, agg_backend=agg_engine.resolve_backend(cfg.agg_backend))
     round_fn = _make_momentum_round(grad_fn, cfg, lr, beta,
                                     gather=_worker_gather(mesh, worker_axis))
 
@@ -1024,8 +1008,6 @@ def _shard_sweep(vseg, mesh, lane_axis: str, worker_axis: str, *,
     ``_worker_gather``."""
     from jax.sharding import PartitionSpec as P
 
-    from repro.launch.mesh import shard_map
-
     lanes = P(lane_axis)
     # batch leaves: (L, m, n_max, ...) — or (R, L, m, ...) with a replicate
     # axis — split on the worker dim; masks lead with the lane (cell) axis
@@ -1033,9 +1015,9 @@ def _shard_sweep(vseg, mesh, lane_axis: str, worker_axis: str, *,
         else P(None, worker_axis)
     xs_specs = (P(), batch_spec, lanes, P())
     in_specs = (lanes, xs_specs) + ((lanes, lanes) if lane else ())
-    return shard_map(vseg, mesh=mesh, in_specs=in_specs,
-                     out_specs=(lanes, lanes),
-                     axis_names={lane_axis, worker_axis}, check_vma=False)
+    return jax.shard_map(vseg, mesh=mesh, in_specs=in_specs,
+                         out_specs=(lanes, lanes),
+                         axis_names={lane_axis, worker_axis}, check_vma=False)
 
 
 def _vmapped_scan_fn(scan_fn, lane: bool = False, replicated: bool = False,

@@ -33,13 +33,15 @@ attacks of Appendix J.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.sharding import PartitionSpec as P
 
-from repro.core.agg_engine import get_aggregator, resolve_backend
+from repro.core.agg_engine import get_aggregator
 
 
 @dataclasses.dataclass(frozen=True)
@@ -57,21 +59,38 @@ class ShardedByzConfig:
 
 
 def _make_leaf_agg(cfg: ShardedByzConfig):
-    """(m, ...) -> (...) robust aggregation via the shared engine registry.
+    """(m, ...) stack, model axis -> (...) robust aggregation via the shared
+    engine registry.
 
     Mode B aggregates each parameter shard independently, which is exact only
     for coordinate-wise rules (DESIGN.md §3) — the engine's registry carries
-    that metadata, so misconfiguration fails at build time, not in backward."""
-    # resolve 'auto' eagerly (pallas on TPU, ref elsewhere): the leaf runs
-    # inside the partial-manual shard_map region, where the per-call size
-    # dispatch must never route a big leaf to an interpret-mode pallas call
-    # the legacy manual lowering cannot host
-    agg = get_aggregator(cfg.aggregator, delta=cfg.delta,
-                         backend=resolve_backend(cfg.backend))
+    that metadata, so misconfiguration fails at build time, not in backward.
+
+    The stack arrives inside the step's partial-manual region (manual over
+    the worker axes, auto over 'model'). A compiled Pallas kernel cannot be
+    partitioned automatically, so the rule runs under a nested ``shard_map``
+    manual over every axis: each device aggregates its own 'model' slice of
+    the coordinates (the leaf's ``model_axis``, -1 when the leaf is
+    replicated over 'model'), exact for a coordinate-wise rule."""
+    agg = get_aggregator(cfg.aggregator, delta=cfg.delta, backend=cfg.backend)
     if not agg.coordinate_wise:
         raise ValueError(
             f"sharded mode supports coordinate-wise rules, got {cfg.aggregator}")
-    return agg.leaf
+
+    def leaf_agg(stack: jax.Array, model_axis: int) -> jax.Array:
+        spec = [None] * stack.ndim
+        if model_axis >= 0:
+            spec[1 + model_axis] = "model"
+        # manual over every axis, the worker axes again included: under
+        # ``jax.set_mesh`` the nested region is lowered against the concrete
+        # mesh, which does not know the outer region made them manual
+        mesh = jax.sharding.get_abstract_mesh()
+        return jax.shard_map(agg.leaf, mesh=mesh, in_specs=P(*spec),
+                             out_specs=P(*spec[1:]),
+                             axis_names=set(mesh.axis_names),
+                             check_vma=False)(stack)
+
+    return leaf_agg
 
 
 def _attack_cotangent(g: jax.Array, maskf: jax.Array, idx: jax.Array,
@@ -101,54 +120,20 @@ def _attack_cotangent(g: jax.Array, maskf: jax.Array, idx: jax.Array,
 
 # ------------------------------------------------------------ collectives
 #
-# jax <= 0.4.x cannot lower worker-axis all_gather / all_to_all inside a
-# *partial*-manual shard_map (the XLA SPMD partitioner check-fails on the
-# ManualSubgroup sharding), and ``lax.axis_index`` lowers to a PartitionId op
-# XLA rejects under partial SPMD. The worker index therefore always arrives
-# as *data* (an iota sharded over the worker axes — see ``make_param_hook``),
-# and on legacy jax the gathers are emulated with psum + dynamic slicing:
-# identical results, m× the gather bytes, never on the production (new-jax
-# TPU) path.
-
-from repro.compat import LEGACY_PARTIAL_MANUAL as _LEGACY_PARTIAL_MANUAL  # noqa: E402
+# The worker index arrives as *data* (an iota sharded over the worker axes —
+# see ``make_param_hook``), which the attack uses to read its own mask entry.
 
 
-def _gather_tiled(p: jax.Array, cfg: ShardedByzConfig, axis: int,
-                  idx: jax.Array) -> jax.Array:
-    """FSDP all-gather along `axis` over the worker axes."""
-    if not _LEGACY_PARTIAL_MANUAL:
-        return lax.all_gather(p, cfg.axis_names, axis=axis, tiled=True)
-    full = jnp.zeros(p.shape[:axis] + (p.shape[axis] * cfg.m,)
-                     + p.shape[axis + 1:], p.dtype)
-    starts = (0,) * axis + (idx * p.shape[axis],) + (0,) * (p.ndim - axis - 1)
-    return lax.psum(lax.dynamic_update_slice(full, p, starts), cfg.axis_names)
-
-
-def _gather_stack(g: jax.Array, cfg: ShardedByzConfig, idx: jax.Array) -> jax.Array:
-    """(...) -> (m, ...): stack the m workers' values of a same-shape array."""
-    if not _LEGACY_PARTIAL_MANUAL:
-        return lax.all_gather(g, cfg.axis_names, axis=0, tiled=False)
-    full = jnp.zeros((cfg.m,) + g.shape, g.dtype)
-    starts = (idx,) + (0,) * g.ndim
-    return lax.psum(lax.dynamic_update_slice(full, g[None], starts), cfg.axis_names)
-
-
-def _exchange_worker_blocks(g: jax.Array, cfg: ShardedByzConfig, axis: int,
-                            idx: jax.Array) -> jax.Array:
+def _exchange_worker_blocks(g: jax.Array, cfg: ShardedByzConfig,
+                            axis: int) -> jax.Array:
     """Worker all-to-all: full-size cotangent -> (m, ..., blk, ...) holding
     every worker's values for this device's own parameter shard."""
-    if not _LEGACY_PARTIAL_MANUAL:
-        ex = lax.all_to_all(g, cfg.axis_names, split_axis=axis,
-                            concat_axis=axis, tiled=True)
-        shp = ex.shape
-        blk = shp[axis] // cfg.m
-        ex = ex.reshape(shp[:axis] + (cfg.m, blk) + shp[axis + 1:])
-        return jnp.moveaxis(ex, axis, 0)
-    stack = _gather_stack(g, cfg, idx)  # (m, ..., d, ...)
-    blk = g.shape[axis] // cfg.m
-    starts = (0,) * (axis + 1) + (idx * blk,) + (0,) * (g.ndim - axis - 1)
-    sizes = (cfg.m,) + g.shape[:axis] + (blk,) + g.shape[axis + 1:]
-    return lax.dynamic_slice(stack, starts, sizes)
+    ex = lax.all_to_all(g, cfg.axis_names, split_axis=axis, concat_axis=axis,
+                        tiled=True)
+    shp = ex.shape
+    blk = shp[axis] // cfg.m
+    ex = ex.reshape(shp[:axis] + (cfg.m, blk) + shp[axis + 1:])
+    return jnp.moveaxis(ex, axis, 0)
 
 
 # ------------------------------------------------- Mode A sharded substrate
@@ -158,10 +143,8 @@ def _exchange_worker_blocks(g: jax.Array, cfg: ShardedByzConfig, axis: int,
 # per-worker gradient computation runs on each device's local worker slice,
 # then the stacks are re-assembled with a worker-axis all_gather so the attack
 # + aggregation code is *identical* to the single-device driver (DESIGN.md
-# §7 — this is what makes the 1-device parity contract bitwise). Unlike the
-# Mode B hooks above, the driver's shard_map region is *fully* manual (the
-# mesh has only worker axes), which legacy jax lowers fine — no psum
-# emulation needed.
+# §7). Unlike the Mode B hooks above, the driver's shard_map region is
+# *fully* manual (the mesh has only worker axes).
 
 
 def gather_worker_stack(tree, axis_names):
@@ -174,31 +157,31 @@ def gather_worker_stack(tree, axis_names):
 # ------------------------------------------------------------ custom VJPs
 
 
-def make_robust_gather(cfg: ShardedByzConfig, gather_axis: int):
+def make_robust_gather(cfg: ShardedByzConfig, gather_axis: int,
+                       model_axis: int):
     """FSDP all-gather whose backward robust-aggregates instead of summing."""
     leaf_agg = _make_leaf_agg(cfg)
 
     @jax.custom_vjp
     def rg(p, maskf, widx):  # widx: f32 scalar worker index (see make_param_hook)
-        return _gather_tiled(p, cfg, gather_axis, widx.astype(jnp.int32))
+        return lax.all_gather(p, cfg.axis_names, axis=gather_axis, tiled=True)
 
     def fwd(p, maskf, widx):
         return rg(p, maskf, widx), (maskf, widx)
 
     def bwd(res, g):
         maskf, widx = res
-        idx = widx.astype(jnp.int32)
-        g = _attack_cotangent(g, maskf, idx, cfg)
+        g = _attack_cotangent(g, maskf, widx.astype(jnp.int32), cfg)
         # exchange: every device ends up with the m worker values of its shard
-        ex = _exchange_worker_blocks(g, cfg, gather_axis, idx)
-        return (leaf_agg(ex).astype(g.dtype), jnp.zeros_like(maskf),
-                jnp.zeros_like(widx))
+        ex = _exchange_worker_blocks(g, cfg, gather_axis)
+        return (leaf_agg(ex, model_axis).astype(g.dtype),
+                jnp.zeros_like(maskf), jnp.zeros_like(widx))
 
     rg.defvjp(fwd, bwd)
     return rg
 
 
-def make_robust_replicated(cfg: ShardedByzConfig):
+def make_robust_replicated(cfg: ShardedByzConfig, model_axis: int):
     """Identity on replicated params; backward gathers the m cotangents and
     robust-aggregates them (small leaves: norms, biases, routers)."""
     leaf_agg = _make_leaf_agg(cfg)
@@ -212,11 +195,10 @@ def make_robust_replicated(cfg: ShardedByzConfig):
 
     def bwd(res, g):
         maskf, widx = res
-        idx = widx.astype(jnp.int32)
-        g = _attack_cotangent(g, maskf, idx, cfg)
-        stack = _gather_stack(g, cfg, idx)  # (m, ...)
-        return (leaf_agg(stack).astype(g.dtype), jnp.zeros_like(maskf),
-                jnp.zeros_like(widx))
+        g = _attack_cotangent(g, maskf, widx.astype(jnp.int32), cfg)
+        stack = lax.all_gather(g, cfg.axis_names, axis=0, tiled=False)
+        return (leaf_agg(stack, model_axis).astype(g.dtype),
+                jnp.zeros_like(maskf), jnp.zeros_like(widx))
 
     rr.defvjp(fwd, bwd)
     return rr
@@ -247,26 +229,25 @@ def make_param_hook(cfg: ShardedByzConfig, plans: dict, maskf: jax.Array,
 
     ``plans``: {scope: plan-tree}, plan trees structurally matching what the
     hook is called on (scope 'blocks' = one group slice; scope 'top' = the
-    non-block params), each leaf an int FSDP axis (-1 => replicated).
-    Built once on global shapes by ``launch.sharding.plan_params``.
+    non-block params), each leaf an int FSDP axis (-1 => replicated), plus
+    ``plans['model']``: {scope: plan-tree} of the leaves' 'model' axes (-1 =>
+    none). Built once on global shapes by ``launch.sharding.plan_params``.
 
     ``widx``: this device's flattened worker index, delivered as data (the
     step builders feed an iota sharded over the worker axes — the local slice
     is the index). Any shape with one element; forwarded as an f32 scalar.
     """
-    rr = make_robust_replicated(cfg)
-    gathers = {ax: make_robust_gather(cfg, ax) for ax in range(4)}
     widx = jnp.asarray(widx, jnp.float32).reshape(())
 
+    @functools.lru_cache(maxsize=None)
+    def vjp_for(fa: int, ma: int):
+        if fa < 0:
+            return make_robust_replicated(cfg, ma)
+        return make_robust_gather(cfg, fa, ma)
+
     def hook(tree, scope: str):
-        plan = plans[scope]
-
-        def leaf(p, fa):
-            if fa < 0:
-                return rr(p, maskf, widx)
-            return gathers[fa](p, maskf, widx)
-
-        return jax.tree.map(leaf, tree, plan)
+        return jax.tree.map(lambda p, fa, ma: vjp_for(fa, ma)(p, maskf, widx),
+                            tree, plans[scope], plans["model"][scope])
 
     return hook
 

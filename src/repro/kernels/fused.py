@@ -48,6 +48,10 @@ _INF = 3.0e38  # python float: becomes a kernel-local constant, not a capture
 
 REDUCE_MODES = ("med", "tm", "mean")
 
+# full f32 matmuls: a TPU's default single bf16 pass would cost the gram
+# expansion of the pairwise stage most of its digits
+_F32 = jax.lax.Precision.HIGHEST
+
 
 def _bitonic_sort_rows(rows):
     """Sort a list of (TILE_D,) f32 rows ascending, element-wise (each
@@ -128,6 +132,7 @@ def _fused_kernel(*refs, m: int, mode, trim: int, has_w: bool, has_t: bool,
     if pairwise:
         i = pl.program_id(0)
         gram = jax.lax.dot_general(x, x, (((1,), (1,)), ((), ())),
+                                   precision=_F32,
                                    preferred_element_type=jnp.float32)
         sq = jnp.diagonal(gram)
         part = sq[:, None] + sq[None, :] - 2.0 * gram
@@ -144,6 +149,7 @@ def _fused_kernel(*refs, m: int, mode, trim: int, has_w: bool, has_t: bool,
     if has_w:
         w = w_ref[...].astype(jnp.float32)  # (k, m)
         y = jax.lax.dot_general(w, x, (((1,), (0,)), ((), ())),
+                                precision=_F32,
                                 preferred_element_type=jnp.float32)
         if combine:
             comb_ref[...] = y

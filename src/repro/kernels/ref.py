@@ -1,7 +1,11 @@
 """Pure-jnp oracles for the aggregation kernels."""
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
+
+# full f32 matmuls, as in the kernels (a TPU's default is one bf16 pass)
+_F32 = jax.lax.Precision.HIGHEST
 
 
 def cwmed_ref(x: jnp.ndarray) -> jnp.ndarray:
@@ -28,7 +32,7 @@ def pairwise_sqdist_ref(x: jnp.ndarray) -> jnp.ndarray:
     """x: (m, d) -> (m, m) squared L2 distances (float32)."""
     x = x.astype(jnp.float32)
     sq = jnp.sum(x * x, axis=1)
-    d2 = sq[:, None] + sq[None, :] - 2.0 * (x @ x.T)
+    d2 = sq[:, None] + sq[None, :] - 2.0 * jnp.matmul(x, x.T, precision=_F32)
     return jnp.maximum(d2, 0.0)
 
 
@@ -48,4 +52,5 @@ def cross_sqdist_ref(x: jnp.ndarray, y: jnp.ndarray) -> jnp.ndarray:
 
 def weighted_combine_ref(x: jnp.ndarray, w: jnp.ndarray) -> jnp.ndarray:
     """x: (m, d), w: (k, m) -> (k, d) = w @ x (float32)."""
-    return w.astype(jnp.float32) @ x.astype(jnp.float32)
+    return jnp.matmul(w.astype(jnp.float32), x.astype(jnp.float32),
+                      precision=_F32)

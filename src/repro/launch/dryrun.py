@@ -25,8 +25,10 @@ import subprocess  # noqa: E402
 import sys  # noqa: E402
 import time  # noqa: E402
 
+import jax  # noqa: E402
+
 from repro.configs import ARCH_IDS, SHAPES, get_config  # noqa: E402
-from repro.launch.mesh import make_production_mesh, set_mesh  # noqa: E402
+from repro.launch.mesh import make_production_mesh  # noqa: E402
 from repro.launch.steps import build_step  # noqa: E402
 
 OUT_DIR = os.environ.get("REPRO_DRYRUN_OUT") or os.path.join(
@@ -184,7 +186,7 @@ def run_one(arch: str, shape_name: str, multi_pod: bool,
     built = build_step(cfg, mesh, shape, aggregator=aggregator, attack=attack,
                        level=level) if shape.kind == "train" else \
         build_step(cfg, mesh, shape)
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         lowered = built.fn.lower(*built.inputs)
         t_lower = time.time() - t0
         t0 = time.time()

@@ -1,4 +1,9 @@
-"""Production meshes.
+"""Device meshes.
+
+Every mesh of the repo is built by :func:`make_mesh`, which gives all axes
+the ``Auto`` axis type: the sharded paths are written for GSPMD propagation
+plus ``shard_map`` regions (DESIGN.md §3, §7, §9), and ``jax.make_mesh``'s
+own default (``Explicit`` axes) rejects their constraints and gathers.
 
 Single pod: 16×16 = 256 chips, axes ("data", "model").
 Multi-pod:  2×16×16 = 512 chips, axes ("pod", "data", "model").
@@ -9,33 +14,20 @@ DynaBRO workers = the data-parallel groups: m=16 (single pod) or m=32
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 
-def set_mesh(mesh):
-    """Context manager activating `mesh`: ``jax.set_mesh`` where it exists
-    (jax >= 0.5), else the Mesh object itself (it is a context manager)."""
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, axis_names, check_vma=False):
-    """``jax.shard_map`` compat: new API where available, else the
-    jax.experimental version (manual over `axis_names`, auto elsewhere)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, axis_names=set(axis_names),
-                             check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _sm
-    auto = frozenset(mesh.axis_names) - set(axis_names)
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=check_vma, auto=auto)
+def make_mesh(shape, axes, devices=None):
+    """``jax.make_mesh`` with every axis of type ``Auto``."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def worker_axes(mesh) -> tuple:
@@ -52,7 +44,7 @@ def n_workers(mesh) -> int:
 
 def make_test_mesh(shape=(2, 2), axes=("data", "model")):
     """Small mesh for CPU tests (requires xla_force_host_platform_device_count)."""
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def worker_spec(waxes):
@@ -90,12 +82,11 @@ def make_worker_mesh(n_devices: int = 0, axis: str = "workers",
         if n * model > len(devs):
             raise ValueError(
                 f"requested {n}x{model} devices, have {len(devs)}")
-        return jax.make_mesh((n, model), (axis, "model"),
-                             devices=devs[: n * model])
+        return make_mesh((n, model), (axis, "model"), devices=devs[: n * model])
     n = n_devices or len(devs)
     if n > len(devs):
         raise ValueError(f"requested {n} devices, have {len(devs)}")
-    return jax.make_mesh((n,), (axis,), devices=devs[:n])
+    return make_mesh((n,), (axis,), devices=devs[:n])
 
 
 def make_lane_mesh(n_lanes: int = 0, n_workers: int = 1,
@@ -115,5 +106,5 @@ def make_lane_mesh(n_lanes: int = 0, n_workers: int = 1,
     if n * n_workers > len(devs):
         raise ValueError(
             f"requested {n}x{n_workers} devices, have {len(devs)}")
-    return jax.make_mesh((n, n_workers), (lane_axis, worker_axis),
-                         devices=devs[: n * n_workers])
+    return make_mesh((n, n_workers), (lane_axis, worker_axis),
+                     devices=devs[: n * n_workers])

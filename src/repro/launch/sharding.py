@@ -63,15 +63,18 @@ def model_axis_rule(path_names: Tuple[str, ...], shape, model_size: int) -> Opti
 
 
 def abstract_params(cfg: ModelConfig, dtype=jnp.bfloat16):
-    return jax.eval_shape(
-        functools.partial(init_params, cfg, dtype=dtype), jax.random.PRNGKey(0))
+    # the key is abstract too: nothing runs, not even the key's creation
+    key = jax.ShapeDtypeStruct((2,), jnp.uint32)
+    return jax.eval_shape(functools.partial(init_params, cfg, dtype=dtype), key)
 
 
 def plan_params(cfg: ModelConfig, mesh: Mesh, *, fsdp: bool, dtype=jnp.bfloat16):
     """Returns (specs, plans):
       specs — PartitionSpec tree matching the full (stacked) param tree;
       plans — {'top': int-tree, 'blocks': int-tree over a group slice},
-              leaf = FSDP gather axis in the local view, -1 = replicated.
+              leaf = FSDP gather axis in the local view, -1 = replicated;
+              plus plans['model'] = {'top': ..., 'blocks': ...}, the same
+              trees holding each leaf's 'model' axis, -1 = none.
     """
     shapes = abstract_params(cfg, dtype)
     model_size = mesh.shape["model"]
@@ -92,32 +95,20 @@ def plan_params(cfg: ModelConfig, mesh: Mesh, *, fsdp: bool, dtype=jnp.bfloat16)
             spec[fa] = waxes if len(waxes) > 1 else waxes[0]
         if stacked:
             spec = [None] + spec
-        return P(*spec), (-1 if fa is None else fa)
+        return (P(*spec), -1 if fa is None else fa,
+                -1 if ma is None else ma)
 
-    top_shapes = {k: v for k, v in shapes.items() if k != "blocks"}
-    top_specs = {}
-    top_plan = {}
-    flat, treedef = jax.tree_util.tree_flatten_with_path(top_shapes)
-    specs_leaves, plan_leaves = [], []
-    for path, leaf in flat:
-        s, pl = entry(path, leaf, stacked=False)
-        specs_leaves.append(s)
-        plan_leaves.append(pl)
-    top_specs = jax.tree_util.tree_unflatten(treedef, specs_leaves)
-    top_plan = jax.tree_util.tree_unflatten(treedef, plan_leaves)
+    def plan(tree, stacked: bool):
+        flat, treedef = jax.tree_util.tree_flatten_with_path(tree)
+        cols = zip(*[entry(path, leaf, stacked) for path, leaf in flat])
+        return [jax.tree_util.tree_unflatten(treedef, list(c)) for c in cols]
 
-    blk_shapes = shapes["blocks"]
-    flatb, treedefb = jax.tree_util.tree_flatten_with_path(blk_shapes)
-    bspecs, bplan = [], []
-    for path, leaf in flatb:
-        s, pl = entry(path, leaf, stacked=True)
-        bspecs.append(s)
-        bplan.append(pl)
-    blk_specs = jax.tree_util.tree_unflatten(treedefb, bspecs)
-    blk_plan = jax.tree_util.tree_unflatten(treedefb, bplan)
-
+    top_specs, top_plan, top_model = plan(
+        {k: v for k, v in shapes.items() if k != "blocks"}, stacked=False)
+    blk_specs, blk_plan, blk_model = plan(shapes["blocks"], stacked=True)
     specs = {**top_specs, "blocks": blk_specs}
-    plans = {"top": top_plan, "blocks": blk_plan}
+    plans = {"top": top_plan, "blocks": blk_plan,
+             "model": {"top": top_model, "blocks": blk_model}}
     return specs, plans
 
 

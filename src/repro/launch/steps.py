@@ -25,16 +25,8 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from repro.configs.base import ModelConfig, ShapeConfig
 from repro.core.sharded import ShardedByzConfig, make_param_hook
 from repro.launch import sharding as shl
-from repro.launch.mesh import (
-    n_workers, shard_map, worker_axes, worker_iota, worker_spec,
-)
+from repro.launch.mesh import n_workers, worker_axes, worker_iota, worker_spec
 from repro.models import loss_fn, decode_step, prefill
-from repro.models import scan_compat
-
-# jax <= 0.4.x: model scans inside the Mode B partial-manual region must
-# unroll, including custom-VJP backward scans traced during the grad sweep —
-# hence the flag wraps the whole local step, not just forward (DESIGN.md §3).
-from repro.compat import LEGACY_PARTIAL_MANUAL as _LEGACY_PARTIAL_MANUAL
 from repro.optim.optimizers import Optimizer, apply_updates, sgd
 
 
@@ -120,7 +112,7 @@ def _wrap_train_step(pl: _TrainPlumbing, step_local, mesh: Mesh, aux_spec,
     """shard_map + jit + example-input assembly shared by both builders."""
     pspecs_manual = shl.strip_model(pl.specs)
     ospecs_manual = shl.strip_model(pl.ospecs)
-    smapped = shard_map(
+    smapped = jax.shard_map(
         step_local, mesh=mesh,
         in_specs=(pspecs_manual, ospecs_manual, pl.batch_spec, P(None),
                   P(worker_spec(pl.waxes))),
@@ -158,10 +150,9 @@ def build_train_step(cfg: ModelConfig, mesh: Mesh, shape: ShapeConfig,
     cfg = pl.cfg
 
     def step_local(params, opt_state, batch, maskf, widx):
-        with scan_compat.unrolled_scans(_LEGACY_PARTIAL_MANUAL):
-            hook = make_param_hook(pl.byz, pl.plans, maskf, widx)
-            loss, g = jax.value_and_grad(
-                lambda p: loss_fn(p, batch, cfg, param_hook=hook))(params)
+        hook = make_param_hook(pl.byz, pl.plans, maskf, widx)
+        loss, g = jax.value_and_grad(
+            lambda p: loss_fn(p, batch, cfg, param_hook=hook))(params)
         updates, opt_state = pl.opt.update(g, opt_state, params)
         params = apply_updates(params, updates)
         loss = jax.lax.pmean(loss, pl.waxes)
@@ -260,6 +251,11 @@ def build_mlmc_train_step(cfg: ModelConfig, mesh: Mesh, shape: ShapeConfig,
     ‖ĝ^J − ĝ^{J−1}‖ a global norm assembled via one scalar psum over the
     worker axes (``core.sharded.make_global_norm``). Beyond-cap levels
     (J > j_max) drop the correction, exactly like the Mode-A drivers.
+
+    The step's third output is ``(loss, failsafe_ok, corr_norm)``: the mean
+    loss over every row the step's gradients used (the level-J batch, or
+    the level-0 prefix beyond the cap), the fail-safe event and
+    ‖ĝ^J − ĝ^{J−1}‖.
     """
     from repro.core.mlmc import level_prefix, mlmc_combine
     from repro.core.sharded import make_global_norm
@@ -272,26 +268,26 @@ def build_mlmc_train_step(cfg: ModelConfig, mesh: Mesh, shape: ShapeConfig,
     norm_fn = make_global_norm(pl.plans, pl.waxes)
 
     def step_local(params, opt_state, batch, maskf, widx):
-        with scan_compat.unrolled_scans(_LEGACY_PARTIAL_MANUAL):
-            hook = make_param_hook(pl.byz, pl.plans, maskf, widx)
+        hook = make_param_hook(pl.byz, pl.plans, maskf, widx)
 
-            def agg_grad(b):
-                # local (per-worker) batch holds (B/m)·2^j rows; the level-n
-                # slice is its nested prefix
-                return jax.grad(
-                    lambda p: loss_fn(p, b, cfg, param_hook=hook))(params)
+        def agg_grad(b):
+            # local (per-worker) batch holds (B/m)·2^j rows; the level-n
+            # slice is its nested prefix
+            return jax.value_and_grad(
+                lambda p: loss_fn(p, b, cfg, param_hook=hook))(params)
 
-            g0 = agg_grad(level_prefix(batch, 1, 2 ** j, axis=0))
-            gjm1 = gj = None
-            if 1 <= j <= mlmc_cfg.j_max:
-                gjm1 = agg_grad(level_prefix(batch, 2 ** (j - 1), 2 ** j,
-                                             axis=0))
-                gj = agg_grad(level_prefix(batch, 2 ** j, 2 ** j, axis=0))
+        loss, g0 = agg_grad(level_prefix(batch, 1, 2 ** j, axis=0))
+        gjm1 = gj = None
+        if 1 <= j <= mlmc_cfg.j_max:
+            _, gjm1 = agg_grad(level_prefix(batch, 2 ** (j - 1), 2 ** j,
+                                            axis=0))
+            loss, gj = agg_grad(level_prefix(batch, 2 ** j, 2 ** j, axis=0))
         g, info = mlmc_combine(g0, gjm1, gj, j, mlmc_cfg, norm_fn=norm_fn)
         updates, opt_state = pl.opt.update(g, opt_state, params)
         params = apply_updates(params, updates)
+        loss = jax.lax.pmean(loss, pl.waxes)
         ok = jax.lax.pmean(info["failsafe_ok"].astype(jnp.float32), pl.waxes)
-        return params, opt_state, (ok, info["corr_norm"])
+        return params, opt_state, (loss, ok, info["corr_norm"])
 
-    return _wrap_train_step(pl, step_local, mesh, (P(), P()),
+    return _wrap_train_step(pl, step_local, mesh, (P(), P(), P()),
                             f"mlmc_train[{cfg.arch_id}/{shape.name}/J{j}]")

@@ -5,8 +5,9 @@ host-side, dispatch to the per-level compiled step (lowered lazily, cached),
 feed per-worker synthetic LM batches, update the Byzantine mask from the
 switching strategy, checkpoint periodically.
 
-On this CPU container, pass ``--devices N`` to spawn N placeholder devices
-(the flag is applied before JAX init via re-exec). Example:
+On a CPU host, ``--devices N`` spawns N placeholder devices (the flag is
+applied before JAX initializes, by re-exec); on an accelerator the mesh is
+built from the attached devices. Example:
 
   PYTHONPATH=src python -m repro.launch.train --arch smollm-360m \\
       --devices 8 --mesh 4x2 --steps 50 --reduced --attack sign_flip \\
@@ -20,21 +21,25 @@ import sys
 import time
 
 
-def _reexec_with_devices(n: int):
+def _reexec_with_devices(n: int, argv):
+    """CPU only: re-exec with ``n`` placeholder host devices (the flag must
+    be set before JAX initializes)."""
     if os.environ.get("_REPRO_DEVICES_SET") == str(n):
         return
     os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "") +
                                f" --xla_force_host_platform_device_count={n}").strip()
     os.environ["_REPRO_DEVICES_SET"] = str(n)
-    os.execv(sys.executable, [sys.executable] + sys.argv)
+    os.execv(sys.executable,
+             [sys.executable, "-m", "repro.launch.train", *argv])
 
 
-def main():
+def _parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="smollm-360m")
     ap.add_argument("--reduced", action="store_true",
                     help="reduced config (CPU-friendly)")
-    ap.add_argument("--devices", type=int, default=0)
+    ap.add_argument("--devices", type=int, default=0,
+                    help="CPU only: placeholder host devices (re-execs)")
     ap.add_argument("--mesh", default="", help="e.g. 4x2 (data x model)")
     ap.add_argument("--steps", type=int, default=50)
     ap.add_argument("--global-batch", type=int, default=8)
@@ -54,10 +59,23 @@ def main():
     ap.add_argument("--ckpt-every", type=int, default=0)
     ap.add_argument("--ckpt-dir", default="checkpoints")
     ap.add_argument("--seed", type=int, default=0)
-    args = ap.parse_args()
+    return ap
+
+
+def main(argv=None) -> list:
+    """Run the CLI on ``argv`` (default: the command line). Returns one
+    record per step: ``{"t", "j", "n_byz", "loss", "seconds"}``, plus
+    ``"failsafe_ok"`` and ``"corr_norm"`` on MLMC levels J ≥ 1; ``seconds``
+    is the step's wall time up to its results being ready (the first call
+    of each level includes compilation)."""
+    argv = sys.argv[1:] if argv is None else list(argv)
+    ap = _parser()
+    args = ap.parse_args(argv)
+    if args.steps < 1:
+        ap.error("--steps must be at least 1")
 
     if args.devices:
-        _reexec_with_devices(args.devices)
+        _reexec_with_devices(args.devices, argv)
 
     import jax
     import jax.numpy as jnp
@@ -69,21 +87,21 @@ def main():
     from repro.core.mlmc import MLMCConfig, sample_level
     from repro.core.switching import get_switcher
     from repro.data import SyntheticLMData
-    from repro.launch.mesh import set_mesh
+    from repro.launch.compile_cache import use_compile_cache
+    from repro.launch.mesh import make_mesh
     from repro.launch.steps import build_mlmc_train_step, build_train_step
     from repro.models import init_params
     from repro.optim.optimizers import get_optimizer
 
+    use_compile_cache()
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = reduced(cfg)
     if args.mesh:
         dims = tuple(int(x) for x in args.mesh.split("x"))
-        axes = ("pod", "data", "model")[-len(dims):]
-        mesh = jax.make_mesh(dims, axes)
+        mesh = make_mesh(dims, ("pod", "data", "model")[-len(dims):])
     else:
-        n = len(jax.devices())
-        mesh = jax.make_mesh((n, 1), ("data", "model"))
+        mesh = make_mesh((len(jax.devices()), 1), ("data", "model"))
     m = 1
     for a in mesh.axis_names:
         if a != "model":
@@ -108,7 +126,7 @@ def main():
 
     def get_step(j):
         if j not in steps_cache:
-            if j == 0 or not args.mlmc:
+            if j == 0:
                 steps_cache[j] = build_train_step(
                     cfg, mesh, shape, aggregator=args.aggregator,
                     attack=args.attack, lr=args.lr, delta=args.delta, opt=opt,
@@ -122,39 +140,53 @@ def main():
     def place(tree, like):
         return jax.tree.map(lambda x, s: jax.device_put(x, s.sharding), tree, like)
 
-    params = init_params(cfg, jax.random.PRNGKey(args.seed), dtype=dtype)
-    opt_state = opt.init(params)
+    def shardings(like):
+        return jax.tree.map(lambda s: s.sharding, like)
+
     rng = np.random.default_rng(args.seed)
-    t_start = time.time()
-    placed = False
-    with set_mesh(mesh):
-        for t in range(args.steps):
-            j = sample_level(rng, mlmc_cfg.j_max) if args.mlmc else 0
-            j = min(j, mlmc_cfg.j_max)
+    levels = [min(sample_level(rng, mlmc_cfg.j_max), mlmc_cfg.j_max)
+              if args.mlmc else 0 for _ in range(args.steps)]
+    first = get_step(levels[0])
+    with jax.set_mesh(mesh):
+        # initial state is created directly in the step's layout, never
+        # whole on one device first
+        params = jax.jit(lambda k: init_params(cfg, k, dtype=dtype),
+                         out_shardings=shardings(first.inputs[0]))(
+            jax.random.PRNGKey(args.seed))
+        opt_state = jax.jit(opt.init, out_shardings=shardings(
+            first.inputs[1]))(params)
+        records = []
+        t_start = time.time()
+        for t, j in enumerate(levels):
             step = get_step(j)
-            if not placed:  # shard initial state per the step's plan
-                params = place(params, step.inputs[0])
-                opt_state = place(opt_state, step.inputs[1])
-                placed = True
-            mult = 2 ** j if (args.mlmc and j > 0) else 1
-            batch = data.batch(t, args.global_batch * mult)
-            batch = place(batch, step.inputs[2])
+            batch = place(data.batch(t, args.global_batch * 2 ** j),
+                          step.inputs[2])
             maskf = place(jnp.asarray(switcher.mask(t), jnp.float32),
                           step.inputs[3])
+            t0 = time.perf_counter()
             params, opt_state, out = step.fn(params, opt_state, batch, maskf)
-            if args.mlmc and j > 0:
-                ok, dn = out
-                msg = f"J={j} failsafe_ok={float(ok):.0f} |ĝJ-ĝJ-1|={float(dn):.3f}"
+            out = jax.block_until_ready(out)
+            rec = {"t": t, "j": j, "n_byz": int(maskf.sum()),
+                   "seconds": time.perf_counter() - t0}
+            if j > 0:
+                loss, ok, dn = out
+                rec.update(failsafe_ok=float(ok), corr_norm=float(dn))
+                msg = (f"J={j} loss={float(loss):.4f} failsafe_ok={float(ok):.0f} "
+                       f"|ĝJ-ĝJ-1|={float(dn):.3f}")
             else:
-                msg = f"loss={float(out):.4f}"
+                loss = out
+                msg = f"loss={float(loss):.4f}"
+            rec["loss"] = float(loss)
+            records.append(rec)
             if t % max(1, args.steps // 20) == 0 or t == args.steps - 1:
-                print(f"step {t:5d} byz={int(maskf.sum())}/{m} {msg} "
+                print(f"step {t:5d} byz={rec['n_byz']}/{m} {msg} "
                       f"({time.time()-t_start:.1f}s)")
             if args.ckpt_every and (t + 1) % args.ckpt_every == 0:
                 save_checkpoint(os.path.join(args.ckpt_dir,
                                              f"{cfg.arch_id}_step{t+1}"),
                                 params, step=t + 1)
     print("done in", round(time.time() - t_start, 1), "s")
+    return records
 
 
 if __name__ == "__main__":

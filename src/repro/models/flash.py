@@ -22,25 +22,22 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro.models.scan_compat import scan as _scan
 from jax.sharding import PartitionSpec as P
 
 NEG_INF = -1e30
 
 
 def _maybe_shard(x, spec):
+    """Pin ``x`` to ``spec`` on the ambient mesh; a no-op where no mesh is
+    set (plain single-device calls) or it lacks an auto axis the spec names."""
     if spec is None:
         return x
-    from repro.models import scan_compat
-    if scan_compat.unrolling_active():
-        # legacy Mode B (partial-manual shard_map on jax <= 0.4.x): a
-        # Sharding annotation here lacks the manual subgroup and trips the
-        # SPMD partitioner (DESIGN.md §3) — drop the perf hint, keep math.
+    mesh = jax.sharding.get_abstract_mesh()
+    named = {a for s in spec if s is not None
+             for a in (s if isinstance(s, tuple) else (s,))}
+    if mesh.empty or not named <= set(mesh.auto_axes):
         return x
-    try:
-        return lax.with_sharding_constraint(x, P(*spec))
-    except Exception:  # no ambient mesh (plain CPU tests)
-        return x
+    return lax.with_sharding_constraint(x, P(*spec))
 
 
 def _pad_to(x, n, axis):
@@ -113,7 +110,7 @@ def _fwd_impl(q, k, v, causal, window, q_offset, kv_chunk, shard_axis,
                         preferred_element_type=jnp.float32)
         return (m_new, l_new, acc * corr[..., None] + pv), None
 
-    (m, l, acc), _ = _scan(kv_body, (m0, l0, a0), jnp.arange(nk))
+    (m, l, acc), _ = lax.scan(kv_body, (m0, l0, a0), jnp.arange(nk))
     out = acc / jnp.maximum(l, 1e-30)[..., None]  # (B,KV,G,Sq,hd)
     lse = m + jnp.log(jnp.maximum(l, 1e-30))
     out = out.transpose(0, 3, 1, 2, 4).reshape(B, Sq, H, hd).astype(q.dtype)
@@ -166,7 +163,7 @@ def _bwd(causal, window, q_offset, kv_chunk, shard_axis, batch_axis, res, dout):
                           preferred_element_type=jnp.float32)
         return dq_acc + dq_c, (dk_c, dv_c)
 
-    dq, (dks, dvs) = _scan(kv_body, dq0, jnp.arange(nk))
+    dq, (dks, dvs) = lax.scan(kv_body, dq0, jnp.arange(nk))
     dq = dq.transpose(0, 3, 1, 2, 4).reshape(B, Sq, H, hd)
     dk = dks.transpose(1, 0, 2, 3, 4).reshape(B, Skp, KV, hd)[:, :Skv]
     dv = dvs.transpose(1, 0, 2, 3, 4).reshape(B, Skp, KV, hd)[:, :Skv]

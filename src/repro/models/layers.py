@@ -12,7 +12,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro.models.scan_compat import scan as _scan
 
 # ---------------------------------------------------------------- norms
 
@@ -152,13 +151,13 @@ def chunked_attention(
                 kv_len=kv_valid)
             return (m, l, acc), None
 
-        (m, l, acc), _ = _scan(kv_body, (m0, l0, a0), jnp.arange(Skv_p // kc_n))
+        (m, l, acc), _ = lax.scan(kv_body, (m0, l0, a0), jnp.arange(Skv_p // kc_n))
         out = acc / jnp.maximum(l, 1e-30)[..., None]
         # (B, KV, G, qc, hd) -> (B, qc, KV*G, hd)
         out = out.transpose(0, 3, 1, 2, 4).reshape(B, qc_n, H, hd)
         return None, out.astype(q.dtype)
 
-    _, outs = _scan(q_body, None, jnp.arange(Sq_p // qc_n))
+    _, outs = lax.scan(q_body, None, jnp.arange(Sq_p // qc_n))
     out = outs.transpose(1, 0, 2, 3, 4).reshape(B, Sq_p, H, hd)
     return out[:, :Sq]
 

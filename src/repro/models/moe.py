@@ -23,21 +23,8 @@ def _capacity(tokens: int, top_k: int, factor: float, E: int) -> int:
 
 def _topk_dispatch(probs: jax.Array, top_k: int, capacity: int):
     """probs: (N, E) -> dispatch (N, E, C) float, combine (N, E, C) float, aux."""
-    from repro.models import scan_compat
     N, E = probs.shape
-    if scan_compat.unrolling_active():
-        # legacy Mode B: the sort partitioner reshards its input to a plain
-        # {replicated} sharding, dropping the manual subgroup (XLA check-
-        # fail, DESIGN.md §3) — take the top_k by iterated argmax instead
-        # (top_k is 1–4; argmax lowers to a plain reduce)
-        masked, cols = jax.lax.stop_gradient(probs), []
-        for _ in range(top_k):
-            i = jnp.argmax(masked, axis=-1)  # (N,)
-            cols.append(i)
-            masked = masked - jax.nn.one_hot(i, E, dtype=masked.dtype) * 1e9
-        idx = jnp.stack(cols, axis=-1)  # (N, k)
-    else:
-        idx = jax.lax.top_k(probs, top_k)[1]  # (N, k) indices only
+    idx = jax.lax.top_k(probs, top_k)[1]  # (N, k) indices only
     # gates re-read probs via one-hots rather than using top_k's value
     # output: the transpose is then a matmul, not a scatter
     gates = jnp.einsum("nke,ne->nk", jax.nn.one_hot(idx, E, dtype=probs.dtype),
@@ -48,18 +35,9 @@ def _topk_dispatch(probs: jax.Array, top_k: int, capacity: int):
     combine = jnp.zeros((N, E, capacity), probs.dtype)
     counts = jnp.zeros((E,), jnp.int32)
     frac_dispatched = jnp.zeros((E,), jnp.float32)
-    if scan_compat.unrolling_active():
-        # legacy Mode B: cumsum lowers to ReduceWindow, which the partial-
-        # manual SPMD partitioner rejects — associative_scan lowers to
-        # log-depth pad/add instead (DESIGN.md §3)
-        def csum(a):
-            return jax.lax.associative_scan(jnp.add, a, axis=0)
-    else:
-        def csum(a):
-            return jnp.cumsum(a, axis=0)
     for k in range(top_k):
         m = jax.nn.one_hot(idx[:, k], E, dtype=jnp.int32)  # (N, E)
-        pos = csum(m) - m + counts[None, :]  # position within expert
+        pos = jnp.cumsum(m, axis=0) - m + counts[None, :]  # position within expert
         counts = counts + m.sum(0)
         keep = (pos < capacity) & (m > 0)
         oh_pos = jax.nn.one_hot(pos, capacity, dtype=probs.dtype)  # (N, E, C)

@@ -11,7 +11,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro.models.scan_compat import scan as _scan
 
 from repro.models.layers import group_norm_heads
 
@@ -65,7 +64,7 @@ def selective_scan(x, delta, A, B, C, D, h0=None, chunk: int = 256):
     def body(h, ci):
         return body_fn(h, (xs[:, ci], dts[:, ci], Bs[:, ci], Cs[:, ci]))
 
-    h_last, ys = _scan(body, h0, jnp.arange(nc))
+    h_last, ys = lax.scan(body, h0, jnp.arange(nc))
     y = ys.transpose(1, 0, 2, 3).reshape(Bt, Lp, di)[:, :L]
     return y, h_last
 
@@ -157,7 +156,7 @@ def _rwkv_wkv_scan(r, k, v, w, u, s0):
 
     seq = (r.transpose(1, 0, 2, 3), k.transpose(1, 0, 2, 3),
            v.transpose(1, 0, 2, 3), w.transpose(1, 0, 2, 3))
-    S, ys = _scan(body, s0, seq)
+    S, ys = lax.scan(body, s0, seq)
     return ys.transpose(1, 0, 2, 3), S  # (Bt, L, H, hd)
 
 

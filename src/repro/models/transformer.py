@@ -19,7 +19,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from repro.configs.base import ModelConfig
-from repro.models import scan_compat, ssm
+from repro.models import ssm
 from repro.models.layers import (
     apply_norm, rms_norm, rope_angles, apply_rope,
     chunked_attention, decode_attention, mlp,
@@ -355,20 +355,8 @@ def _encoder_forward(params, frames, cfg: ModelConfig):
     return apply_norm(x, params["encoder"]["final_norm"], cfg.norm)
 
 
-# jax <= 0.4.x: XLA sharding propagation cannot handle gather/scatter HLOs
-# inside a partial-manual shard_map region (hlo_sharding_util IsManualSubgroup
-# check-fail), so Mode B (param_hook active) swaps the token gathers for
-# one-hot matmuls on that path — gather-free, and so is the transpose.
-from repro.compat import LEGACY_PARTIAL_MANUAL as _LEGACY_PARTIAL_MANUAL
-
-
-def _embed_tokens(params, tokens, cfg, pos=None, gatherless=False):
-    if gatherless:
-        onehot = jax.nn.one_hot(tokens, params["embed"].shape[0],
-                                dtype=params["embed"].dtype)
-        x = onehot @ params["embed"]
-    else:
-        x = params["embed"][tokens]
+def _embed_tokens(params, tokens, cfg, pos=None):
+    x = params["embed"][tokens]
     if cfg.family == "audio":
         if pos is not None:  # decode: single absolute position
             x = x + lax.dynamic_slice_in_dim(params["dec_pos"], pos, 1, 0)[None]
@@ -404,8 +392,7 @@ def forward(params: Params, tokens: jax.Array, cfg: ModelConfig,
     if param_hook is not None:
         top = {k: v for k, v in params.items() if k != "blocks"}
         params = {**param_hook(top, "top"), "blocks": params["blocks"]}
-    x = _embed_tokens(params, tokens, cfg,
-                      gatherless=param_hook is not None and _LEGACY_PARTIAL_MANUAL)
+    x = _embed_tokens(params, tokens, cfg)
     kv_src = _kv_src(params, cfg, extra or {})
     pattern = cfg.pattern()
 
@@ -436,13 +423,7 @@ def forward(params: Params, tokens: jax.Array, cfg: ModelConfig,
     body = group_body
     if remat and mode == "train":
         body = jax.checkpoint(group_body, prevent_cse=False)
-    # Mode B on legacy jax: every scan below here must unroll (while loops
-    # cannot carry partial-manual shardings through XLA <= 0.4.x — see
-    # models.scan_compat); covers this group loop and the attention/SSM
-    # chunk scans inside the blocks.
-    with scan_compat.unrolled_scans(
-            param_hook is not None and _LEGACY_PARTIAL_MANUAL):
-        x, (auxs, caches) = scan_compat.scan(body, x, params["blocks"])
+    x, (auxs, caches) = lax.scan(body, x, params["blocks"])
     logits = _unembed(params, x, cfg)
     aux = jnp.sum(auxs)
     if mode == "prefill":
@@ -457,11 +438,7 @@ def loss_fn(params: Params, batch: dict, cfg: ModelConfig, param_hook=None) -> j
     labels = batch["labels"]
     logits = logits.astype(jnp.float32)
     lse = jax.scipy.special.logsumexp(logits, axis=-1)
-    if param_hook is not None and _LEGACY_PARTIAL_MANUAL:
-        gold = jnp.sum(logits * jax.nn.one_hot(labels, logits.shape[-1],
-                                               dtype=logits.dtype), axis=-1)
-    else:
-        gold = jnp.take_along_axis(logits, labels[..., None], axis=-1)[..., 0]
+    gold = jnp.take_along_axis(logits, labels[..., None], axis=-1)[..., 0]
     nll = (lse - gold).mean()
     return nll + cfg.router_aux_weight * aux
 

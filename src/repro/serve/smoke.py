@@ -75,9 +75,12 @@ def main() -> int:
     if server.error is not None:
         print(f"server error: {server.error!r}", file=sys.stderr)
         return 1
-    for a, b in zip(np.asarray(server.params["x"]),
-                    np.asarray(params_ref["x"])):
-        assert a == b, (server.params, params_ref)
+    got, want = np.asarray(server.params["x"]), np.asarray(params_ref["x"])
+    if not np.array_equal(got, want):
+        print(f"served carry != offline driver: max |diff| = "
+              f"{np.abs(got - want).max():.3g} ({got} vs {want})",
+              file=sys.stderr)
+        return 1
     assert [(lg.level, lg.failsafe_ok) for lg in server.logs] == \
            [(lg.level, lg.failsafe_ok) for lg in logs_ref]
     assert sum(1 for e in events if e.get("event") == "round") == T

@@ -36,7 +36,7 @@ from repro.core.scenarios import (
     format_table, make_quadratic_task, run_matrix, scenario_grid,
 )
 from repro.core.switching import get_switcher
-from repro.launch.mesh import make_lane_mesh
+from repro.launch.mesh import make_lane_mesh, make_mesh
 from repro.optim.optimizers import sgd
 
 SRC = os.path.join(os.path.dirname(__file__), "..", "src")
@@ -218,9 +218,8 @@ def test_one_device_lane_mesh_is_bitwise():
 def test_lane_mesh_validation():
     sess = _sess()
     with pytest.raises(ValueError, match="lanes"):
-        import jax
         sess.sweep(SweepSpec(switchers=SWS, seeds=(0, 1)), T,
-                   lane_mesh=jax.make_mesh((1,), ("data",)))
+                   lane_mesh=make_mesh((1,), ("data",)))
 
 
 def _run(body: str):

@@ -2,10 +2,13 @@
 
 Contract: ``run_dynabro_scan(..., mesh=...)`` / ``run_momentum_scan(...,
 mesh=...)`` lay the m simulated workers across the devices of a 1-axis
-``workers`` mesh and are **bitwise identical** to the unsharded driver — on a
-1-device mesh by construction (the acceptance contract, tested in-process),
-and across real device counts because only the per-worker gradient vmap is
-split; the attack/aggregation/update body runs on the gathered full stack.
+``workers`` mesh and match the unsharded driver: **bitwise** on a 1-device
+mesh by construction (the acceptance contract, tested in-process), and
+within ``rtol=atol=1e-6`` with identical fail-safe traces across real
+device counts. Only the per-worker gradient vmap is split and the
+attack/aggregation/update body runs on the gathered full stack, but the
+all-gather is a fusion boundary that moves a few gradient entries by one
+ulp (DESIGN.md §7).
 
 Multi-device cases run in subprocesses with forced host devices so the main
 pytest process keeps seeing 1 CPU device (same pattern as test_sharded.py).
@@ -24,7 +27,7 @@ from repro.core.robust_train import (
 )
 from repro.core.scenarios import make_quadratic_task, run_scenario, scenario_grid
 from repro.core.switching import get_switcher
-from repro.launch.mesh import make_worker_mesh
+from repro.launch.mesh import make_mesh, make_worker_mesh
 from repro.optim.optimizers import sgd
 
 SRC = os.path.join(os.path.dirname(__file__), "..", "src")
@@ -93,13 +96,11 @@ def test_sharded_scenario_cell_matches_unsharded():
 
 
 def test_sharded_rejects_bad_meshes():
-    import jax
-
     cfg = _cfg()
     with pytest.raises(ValueError, match="1-axis"):
         run_dynabro_scan(TASK.grad_fn, TASK.params0, sgd(2e-2), cfg, _sw(),
                          TASK.make_sampler(M), 8,
-                         mesh=jax.make_mesh((1, 1), ("data", "model")))
+                         mesh=make_mesh((1, 1), ("data", "model")))
     # m=9 on a 2-device axis cannot split evenly -> build-time error; needs
     # >=2 devices, so exercise it in a subprocess
     _run("""
@@ -145,9 +146,12 @@ def _run(body: str):
 
 @pytest.mark.slow
 def test_sharded_multi_device_parity():
-    """m=8 workers across 2/4/8 devices: bitwise parity with the unsharded
-    driver, including the omniscient attacks whose statistics span the whole
-    (post-gather) worker stack, and identical fail-safe traces."""
+    """m=8 workers across 2/4/8 devices: parity with the unsharded driver,
+    including the omniscient attacks whose statistics span the whole
+    (post-gather) worker stack, and identical fail-safe traces. Not bitwise:
+    the all-gather is a fusion boundary, and XLA contracts the per-worker
+    gradient's multiply-adds differently on each side of it, so a few
+    entries differ by one ulp (DESIGN.md §7)."""
     _run("""
         for attack in ("sign_flip", "ipm", "alie"):
             cfg = DynaBROConfig(mlmc=MLMCConfig(T=T, m=m, V=3.0, kappa=1.0),
@@ -159,8 +163,9 @@ def test_sharded_multi_device_parity():
                 p, l, _ = run_dynabro_scan(task.grad_fn, task.params0,
                                            sgd(2e-2), cfg, sw(), sampler, T,
                                            seed=4, mesh=make_worker_mesh(nd))
-                np.testing.assert_array_equal(np.asarray(p0["x"]),
-                                              np.asarray(p["x"]))
+                np.testing.assert_allclose(np.asarray(p["x"]),
+                                           np.asarray(p0["x"]),
+                                           rtol=1e-6, atol=1e-6)
                 assert [x.failsafe_ok for x in l0] == [x.failsafe_ok for x in l]
         print("OK")
     """)

@@ -10,6 +10,7 @@ import pytest
 from repro.configs import get_reduced_config
 from repro.configs.base import ShapeConfig
 from repro.core.mlmc import MLMCConfig
+from repro.launch.mesh import make_mesh
 from repro.launch.steps import build_mlmc_train_step, build_train_step
 
 # one arch per family: dense, moe, hybrid, ssm, audio, vlm
@@ -26,7 +27,7 @@ FAMILY_ARCHS = [
 @pytest.mark.parametrize("arch", FAMILY_ARCHS)
 def test_mlmc_batch_sds_matches_train_step(arch):
     cfg = get_reduced_config(arch)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     shape = ShapeConfig("t", 16, 4, "train")
     mc = MLMCConfig(T=8, m=1, V=1e9)
     bs = build_train_step(cfg, mesh, shape, dtype=jnp.float32)
