@@ -38,6 +38,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.api.specs import SweepSpec
 from repro.core import robust_train as rt
 from repro.core.mlmc import round_cost, sample_level
@@ -315,19 +316,21 @@ class Session:
             if self.mode == "dynabro":
                 return self._run_legacy_dynabro(T, eval_fn, eval_every, step)
             return self._run_legacy_momentum(T, eval_fn, eval_every, step)
-        if self.mode == "dynabro":
-            return self._run_scan_dynabro(T, eval_fn, eval_every, chunk)
-        return self._run_scan_momentum(T, eval_fn, eval_every, chunk)
+        with obs.span("repro.run", rounds=T):
+            if self.mode == "dynabro":
+                return self._run_scan_dynabro(T, eval_fn, eval_every, chunk)
+            return self._run_scan_momentum(T, eval_fn, eval_every, chunk)
 
     def _run_scan_dynabro(self, T, eval_fn, eval_every, chunk):
         if T <= 0:
             return self.params0, [], []
-        sched = self.schedule(T)
+        with obs.span("repro.schedule"):
+            sched = self.schedule(T)
+            masks_dev = jnp.asarray(sched.masks)
+            keys_dev = jnp.asarray(sched.keys)
+            levels_dev = jnp.asarray(sched.levels)
         scan_fn = self.scan_fn
         carry = self.init_carry()
-        masks_dev = jnp.asarray(sched.masks)
-        keys_dev = jnp.asarray(sched.keys)
-        levels_dev = jnp.asarray(sched.levels)
         oks, evals = [], []
         a = 0
         for b in rt._segment_bounds(T, eval_every if eval_fn else 0, chunk):
@@ -336,27 +339,31 @@ class Session:
                 sched.n_max, vectorize=self.vectorize_batches)
             xs = (levels_dev[a:b], batches, masks_dev[a:b], keys_dev[a:b])
             with self._steady_guard("run", xs,
-                                    f"Session.run segment [{a}:{b}]"):
+                                    f"Session.run segment [{a}:{b}]"), \
+                    obs.span("repro.dispatch"):
                 carry, (ok, _dn) = scan_fn(carry, xs)
-            oks.append(np.asarray(ok))
+            with obs.span("repro.wait"):
+                oks.append(np.asarray(ok))
             sanitizers.maybe_assert_finite(
                 carry[0], f"Session.run segment [{a}:{b}]",
                 enabled=self.nan_tripwire)
             if eval_fn and eval_every and b % eval_every == 0:
-                evals.append((b, eval_fn(carry[0], b - 1)))
+                with obs.span("repro.eval"):
+                    evals.append((b, eval_fn(carry[0], b - 1)))
             a = b
-        ok_all = np.concatenate(oks) if oks else np.zeros(0, bool)
-        return (carry[0],
-                rt._round_logs(sched.levels, ok_all, sched.masks,
-                               self.cfg.mlmc.j_max),
-                evals)
+        with obs.span("repro.results"):
+            ok_all = np.concatenate(oks) if oks else np.zeros(0, bool)
+            logs = rt._round_logs(sched.levels, ok_all, sched.masks,
+                                  self.cfg.mlmc.j_max)
+        return carry[0], logs, evals
 
     def _run_scan_momentum(self, T, eval_fn, eval_every, chunk):
         if T <= 0:
             return self.params0, []
-        sched = self.schedule(T)
-        masks = jnp.asarray(sched.masks)  # (T, m)
-        keys = jnp.asarray(sched.keys)
+        with obs.span("repro.schedule"):
+            sched = self.schedule(T)
+            masks = jnp.asarray(sched.masks)  # (T, m)
+            keys = jnp.asarray(sched.keys)
         scan_fn = self.scan_fn
         carry = self.init_carry()
         evals = []
@@ -368,13 +375,15 @@ class Session:
             batches = jax.tree.map(lambda l: l[:, :, 0], bsched)  # (L, m, ...)
             xs = (batches, masks[a:b], keys[a:b])
             with self._steady_guard("run", xs,
-                                    f"Session.run segment [{a}:{b}]"):
+                                    f"Session.run segment [{a}:{b}]"), \
+                    obs.span("repro.dispatch"):
                 carry, _ = scan_fn(carry, xs)
             sanitizers.maybe_assert_finite(
                 carry[0], f"Session.run segment [{a}:{b}]",
                 enabled=self.nan_tripwire)
             if eval_fn and eval_every and b % eval_every == 0:
-                evals.append((b, eval_fn(carry[0], b - 1)))
+                with obs.span("repro.eval"):
+                    evals.append((b, eval_fn(carry[0], b - 1)))
             a = b
         return carry[0], evals
 
@@ -564,6 +573,14 @@ class Session:
         if self.mode != "dynabro":
             raise ValueError("sweeps are dynabro-mode only")
         spec = spec if isinstance(spec, SweepSpec) else SweepSpec(**spec)
+        with obs.span("repro.sweep", lanes=spec.lanes * spec.n_replicates,
+                      rounds=T):
+            return self._sweep(spec, T, chunk, lane_chunk, lane_mesh,
+                               lane_axis)
+
+    def _sweep(self, spec: SweepSpec, T: int, chunk: int, lane_chunk: int,
+               lane_mesh, lane_axis: str) -> List[Any]:
+        """The body of ``sweep``; chunks and rule groups recurse here."""
         cfg, opt, params = self.cfg, self.opt, self.params0
         C = spec.lanes
         R = spec.n_replicates
@@ -582,9 +599,10 @@ class Session:
             for a in range(0, C, lane_chunk):
                 sub = spec.lane_subset(range(a, min(a + lane_chunk, C)),
                                        scan_fn=spec.scan_fn)
-                outs.extend(self.sweep(sub, T, chunk=chunk,
-                                       lane_mesh=lane_mesh,
-                                       lane_axis=lane_axis))
+                with obs.span("repro.sweep.chunk",
+                              lanes=sub.lanes * R):
+                    outs.extend(self._sweep(sub, T, chunk, 0, lane_mesh,
+                                            lane_axis))
             return outs
 
         attacks = spec.attack_lanes()
@@ -619,37 +637,42 @@ class Session:
                 for name in distinct:
                     idx = [c for c in range(C)
                            if aggregators[c][0] == name]
-                    sub = self.sweep(
-                        spec.lane_subset(
-                            idx, scan_fn=(None if group_fns is None
-                                          else group_fns[name])),
-                        T, chunk=chunk, lane_mesh=lane_mesh,
-                        lane_axis=lane_axis)
+                    with obs.span("repro.sweep.group", lanes=len(idx) * R):
+                        sub = self._sweep(
+                            spec.lane_subset(
+                                idx, scan_fn=(None if group_fns is None
+                                              else group_fns[name])),
+                            T, chunk, 0, lane_mesh, lane_axis)
                     for j, c in enumerate(idx):
                         outs[c] = sub[j]
                 return outs
             if group_fns is not None:  # single distinct rule: unwrap and run
                 scan_fn = group_fns[distinct[0]]
 
-        (levels, ns, n_max, masks, keys, samplers, replicated,
-         m) = self._sweep_streams(spec, T)
-        self._check_sweep_lane_mesh(lane_mesh, lane_axis, C, m)
-        atk = agg = atk_names = agg_names = None
-        if attacks is not None:
-            atk_names, ids, thetas = rt._lane_attack_plan(attacks)
-            atk = (jnp.asarray(ids), jnp.asarray(thetas))
-        if aggregators is not None:
-            agg_names, gids, gthetas, coeffs = rt._lane_agg_plan(aggregators,
-                                                                 cfg)
-            agg = (jnp.asarray(gids), jnp.asarray(gthetas),
-                   jnp.asarray(coeffs))
+        with obs.span("repro.schedule"):
+            (levels, ns, n_max, masks, keys, samplers, replicated,
+             m) = self._sweep_streams(spec, T)
+            self._check_sweep_lane_mesh(lane_mesh, lane_axis, C, m)
+            atk = agg = atk_names = agg_names = None
+            if attacks is not None:
+                atk_names, ids, thetas = rt._lane_attack_plan(attacks)
+                atk = (jnp.asarray(ids), jnp.asarray(thetas))
+            if aggregators is not None:
+                agg_names, gids, gthetas, coeffs = rt._lane_agg_plan(
+                    aggregators, cfg)
+                agg = (jnp.asarray(gids), jnp.asarray(gthetas),
+                       jnp.asarray(coeffs))
+            masks_dev, keys_dev = jnp.asarray(masks), jnp.asarray(keys)
+            levels_dev = jnp.asarray(levels)
         lane_mode = atk is not None or agg is not None
         scan_fn, lm = self._sweep_scan_fn(scan_fn, cfg, atk_names, agg_names,
                                           lane_mesh, lane_axis)
+        misses = rt._VMAPPED_MISSES
         vseg = rt._vmapped_scan_fn(scan_fn, lane=lane_mode,
                                    replicated=replicated, lane_mesh=lm,
                                    lane_axis=lane_axis,
                                    worker_axis=self.worker_axis)
+        traced = rt._VMAPPED_MISSES - misses
 
         def lanes(tree):  # identical initial state in every lane
             lead = (C, R) if replicated else (C,)
@@ -657,8 +680,6 @@ class Session:
                 lambda l: jnp.broadcast_to(l, lead + l.shape), tree)
 
         carry = (lanes(params), lanes(opt.init(params)))
-        masks_dev, keys_dev = jnp.asarray(masks), jnp.asarray(keys)
-        levels_dev = jnp.asarray(levels)
 
         oks = []
         a = 0
@@ -671,23 +692,27 @@ class Session:
             else:
                 xs = (levels_dev[a:b], batches, masks_dev[:, a:b],
                       keys_dev[a:b])
-            if lane_mode:
-                carry, (ok, _dn) = vseg(carry, xs, atk, agg)
-            else:
-                carry, (ok, _dn) = vseg(carry, xs)
-            oks.append(np.asarray(ok))  # (C, [R,] b - a)
+            with obs.span("repro.dispatch", traced=traced):
+                if lane_mode:
+                    carry, (ok, _dn) = vseg(carry, xs, atk, agg)
+                else:
+                    carry, (ok, _dn) = vseg(carry, xs)
+            traced = 0
+            with obs.span("repro.wait"):
+                oks.append(np.asarray(ok))  # (C, [R,] b - a)
             a = b
-        ok_all = np.concatenate(oks, axis=-1)
-        if not replicated:
-            return [(jax.tree.map(lambda l, c=c: l[c], carry[0]),
-                     rt._round_logs(levels, ok_all[c], masks[c],
-                                    cfg.mlmc.j_max))
+        with obs.span("repro.results", lanes=C * R):
+            ok_all = np.concatenate(oks, axis=-1)
+            if not replicated:
+                return [(jax.tree.map(lambda l, c=c: l[c], carry[0]),
+                         rt._round_logs(levels, ok_all[c], masks[c],
+                                        cfg.mlmc.j_max))
+                        for c in range(C)]
+            return [[(jax.tree.map(lambda l, c=c, r=r: l[c, r], carry[0]),
+                      rt._round_logs(levels, ok_all[c, r], masks[c, r],
+                                     cfg.mlmc.j_max))
+                     for r in range(R)]
                     for c in range(C)]
-        return [[(jax.tree.map(lambda l, c=c, r=r: l[c, r], carry[0]),
-                  rt._round_logs(levels, ok_all[c, r], masks[c, r],
-                                 cfg.mlmc.j_max))
-                 for r in range(R)]
-                for c in range(C)]
 
     def sweep_halving(self, spec: SweepSpec, T: int, *,
                       objective: Callable[[Any], float],
@@ -735,28 +760,43 @@ class Session:
             raise ValueError(
                 f"rungs= must be strictly increasing round counts in "
                 f"(0, T={T}), got {rungs}")
+        with obs.span("repro.sweep_halving", lanes=C * R, rounds=T):
+            return self._sweep_halving(spec, T, objective, keep, rungs,
+                                       lane_mesh, lane_axis, min_cells)
 
+    def _sweep_halving(self, spec: SweepSpec, T: int, objective, keep: float,
+                       rungs: List[int], lane_mesh, lane_axis: str,
+                       min_cells: int) -> List[Dict[str, Any]]:
+        """The body of ``sweep_halving``, past its checks."""
+        cfg = self.cfg
+        C = spec.lanes
+        R = spec.n_replicates
         attacks = spec.attack_lanes()
         aggregators = spec.agg_lanes()
-        (levels, ns, n_max, masks, keys, samplers, replicated,
-         m) = self._sweep_streams(spec, T)
-        self._check_sweep_lane_mesh(lane_mesh, lane_axis, C, m)
-        atk = agg = atk_names = agg_names = None
-        if attacks is not None:
-            atk_names, ids, thetas = rt._lane_attack_plan(attacks)
-            atk = (jnp.asarray(ids), jnp.asarray(thetas))
-        if aggregators is not None:
-            agg_names, gids, gthetas, coeffs = rt._lane_agg_plan(aggregators,
-                                                                 cfg)
-            agg = (jnp.asarray(gids), jnp.asarray(gthetas),
-                   jnp.asarray(coeffs))
+        with obs.span("repro.schedule"):
+            (levels, ns, n_max, masks, keys, samplers, replicated,
+             m) = self._sweep_streams(spec, T)
+            self._check_sweep_lane_mesh(lane_mesh, lane_axis, C, m)
+            atk = agg = atk_names = agg_names = None
+            if attacks is not None:
+                atk_names, ids, thetas = rt._lane_attack_plan(attacks)
+                atk = (jnp.asarray(ids), jnp.asarray(thetas))
+            if aggregators is not None:
+                agg_names, gids, gthetas, coeffs = rt._lane_agg_plan(
+                    aggregators, cfg)
+                agg = (jnp.asarray(gids), jnp.asarray(gthetas),
+                       jnp.asarray(coeffs))
+            masks_dev, keys_dev = jnp.asarray(masks), jnp.asarray(keys)
+            levels_dev = jnp.asarray(levels)
         lane_mode = atk is not None or agg is not None
         scan_fn, lm = self._sweep_scan_fn(spec.scan_fn, cfg, atk_names,
                                           agg_names, lane_mesh, lane_axis)
+        misses = rt._VMAPPED_MISSES
         vseg = rt._vmapped_scan_fn(scan_fn, lane=lane_mode,
                                    replicated=replicated, lane_mesh=lm,
                                    lane_axis=lane_axis,
                                    worker_axis=self.worker_axis)
+        traced = rt._VMAPPED_MISSES - misses
         n_lanes_mesh = lm.shape[lane_axis] if lm is not None else 1
 
         def lanes(tree):
@@ -781,8 +821,6 @@ class Session:
                     for r in range(R)]
 
         carry = (lanes(self.params0), lanes(self.opt.init(self.params0)))
-        masks_dev, keys_dev = jnp.asarray(masks), jnp.asarray(keys)
-        levels_dev = jnp.asarray(levels)
         alive = list(range(C))  # original cell index per live lane
         outs: List[Optional[Dict[str, Any]]] = [None] * C
         oks: List[np.ndarray] = []
@@ -797,11 +835,14 @@ class Session:
             else:
                 xs = (levels_dev[a:b], batches,
                       masks_dev[jnp.asarray(alive)][:, a:b], keys_dev[a:b])
-            if lane_mode:
-                carry, (ok, _dn) = vseg(carry, xs, atk, agg)
-            else:
-                carry, (ok, _dn) = vseg(carry, xs)
-            oks.append(np.asarray(ok))
+            with obs.span("repro.dispatch", traced=traced):
+                if lane_mode:
+                    carry, (ok, _dn) = vseg(carry, xs, atk, agg)
+                else:
+                    carry, (ok, _dn) = vseg(carry, xs)
+            traced = 0
+            with obs.span("repro.wait"):
+                oks.append(np.asarray(ok))
             ok_all = np.concatenate(oks, axis=-1)  # (C_live, [R,] b)
             if b == T:
                 break
@@ -832,10 +873,11 @@ class Session:
                 oks = [o[np.asarray(keep_local)] for o in oks]
                 alive = [alive[j] for j in keep_local]
             a = b
-        ok_all = np.concatenate(oks, axis=-1)
-        for j, cell in enumerate(alive):
-            outs[cell] = {"pruned": False, "rounds_run": T,
-                          "results": cell_out(carry, ok_all, j, cell)}
+        with obs.span("repro.results", lanes=len(alive) * R):
+            ok_all = np.concatenate(oks, axis=-1)
+            for j, cell in enumerate(alive):
+                outs[cell] = {"pruned": False, "rounds_run": T,
+                              "results": cell_out(carry, ok_all, j, cell)}
         return outs
 
 

@@ -22,6 +22,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core import agg_engine
 from repro.core import attacks as attacks_lib
 from repro.core.aggregators import MFM, get_aggregator
@@ -334,41 +335,49 @@ def _batch_schedule(sample_batches, tn, n_max: int, vectorize: bool = True):
     calls the sampler exactly once per round, in round order, like the legacy
     driver.
     """
-    if vectorize:
-        try:
-            groups: Dict[int, list] = {}
-            for i, (t, n) in enumerate(tn):
-                groups.setdefault(int(n), []).append((i, int(t)))
-            out = None
-            for n, its in sorted(groups.items()):
-                idx = jnp.asarray(np.array([i for i, _ in its], np.int32))
-                ts = jnp.asarray(np.array([t for _, t in its], np.int32))
-                bt = jax.vmap(lambda t: sample_batches(t, n))(ts)
-                bt = _pad_units(bt, n_max, axis=2)
-                if out is None:
-                    out = jax.tree.map(
-                        lambda l: jnp.zeros((len(tn),) + l.shape[1:], l.dtype),
-                        bt)
-                out = jax.tree.map(lambda o, l: o.at[idx].set(l), out, bt)
-            n_probe, its_probe = max(groups.items(), key=lambda kv: len(kv[1]))
-            i0, t0 = its_probe[-1]
-            want = _pad_units(sample_batches(t0, n_probe), n_max, axis=1)
-            got = jax.tree.map(lambda l: l[i0], out)
-            if not all(bool(jnp.array_equal(a, b)) for a, b in
-                       zip(jax.tree.leaves(got), jax.tree.leaves(want))):
-                raise ValueError("vectorized sampler disagrees with direct call")
-            return out
-        except (TypeError, ValueError) as e:
-            # TypeError: sampler not traceable in t (jax tracer-leak errors
-            # subclass it); ValueError: probe mismatch / host-side shape
-            # complaints. Anything else (OOM, internal bugs) propagates —
-            # silently reverting to O(T) dispatch would mask it.
-            warnings.warn(
-                f"run_*_scan: per-round batch sampling fallback ({e}); pass "
-                "vectorize_batches=False to silence", RuntimeWarning)
-    rows = [_pad_units(sample_batches(t, int(n)), n_max, axis=1)
-            for t, n in tn]
-    return jax.tree.map(lambda *ls: jnp.stack(ls), *rows)
+    with obs.span("repro.batches") as span:
+        if vectorize:
+            try:
+                return _vectorized_batches(sample_batches, tn, n_max)
+            except (TypeError, ValueError) as e:
+                # TypeError: sampler not traceable in t (jax tracer-leak
+                # errors subclass it); ValueError: probe mismatch / host-side
+                # shape complaints. Anything else (OOM, internal bugs)
+                # propagates — silently reverting to O(T) dispatch would mask
+                # it.
+                warnings.warn(
+                    f"run_*_scan: per-round batch sampling fallback ({e}); "
+                    "pass vectorize_batches=False to silence", RuntimeWarning)
+                span.add("fallback")
+        rows = [_pad_units(sample_batches(t, int(n)), n_max, axis=1)
+                for t, n in tn]
+        return jax.tree.map(lambda *ls: jnp.stack(ls), *rows)
+
+
+def _vectorized_batches(sample_batches, tn, n_max: int):
+    """``_batch_schedule``'s vectorized path: one vmapped sampler call per
+    level group, scattered into place, then the probe round."""
+    groups: Dict[int, list] = {}
+    for i, (t, n) in enumerate(tn):
+        groups.setdefault(int(n), []).append((i, int(t)))
+    out = None
+    for n, its in sorted(groups.items()):
+        idx = jnp.asarray(np.array([i for i, _ in its], np.int32))
+        ts = jnp.asarray(np.array([t for _, t in its], np.int32))
+        bt = jax.vmap(lambda t: sample_batches(t, n))(ts)
+        bt = _pad_units(bt, n_max, axis=2)
+        if out is None:
+            out = jax.tree.map(
+                lambda l: jnp.zeros((len(tn),) + l.shape[1:], l.dtype), bt)
+        out = jax.tree.map(lambda o, l: o.at[idx].set(l), out, bt)
+    n_probe, its_probe = max(groups.items(), key=lambda kv: len(kv[1]))
+    i0, t0 = its_probe[-1]
+    want = _pad_units(sample_batches(t0, n_probe), n_max, axis=1)
+    got = jax.tree.map(lambda l: l[i0], out)
+    if not all(bool(jnp.array_equal(a, b)) for a, b in
+               zip(jax.tree.leaves(got), jax.tree.leaves(want))):
+        raise ValueError("vectorized sampler disagrees with direct call")
+    return out
 
 
 def _level_plan(cfg: DynaBROConfig, rng: np.random.Generator, T: int):
@@ -994,6 +1003,7 @@ def run_momentum_scan(
 
 _VMAPPED_CACHE: list = []  # MRU-first [(scan_fn, config_key, vseg), ...]
 _VMAPPED_CACHE_SIZE = 8
+_VMAPPED_MISSES = 0  # wrappers built (each traces on its first call)
 
 
 def _shard_sweep(vseg, mesh, lane_axis: str, worker_axis: str, *,
@@ -1041,6 +1051,7 @@ def _vmapped_scan_fn(scan_fn, lane: bool = False, replicated: bool = False,
     per-cell. ``lane_mesh`` (2-axis, multi-device) additionally wraps the
     result in ``_shard_sweep``; a 1-device mesh is ignored here so the
     traced graph is the unsharded one (bitwise by construction)."""
+    global _VMAPPED_MISSES
     if lane_mesh is not None and \
             math.prod(list(lane_mesh.shape.values())) == 1:
         lane_mesh = None
@@ -1049,6 +1060,7 @@ def _vmapped_scan_fn(scan_fn, lane: bool = False, replicated: bool = False,
         if entry[0] is scan_fn and entry[1] == key:
             _VMAPPED_CACHE.insert(0, _VMAPPED_CACHE.pop(i))
             return entry[2]
+    _VMAPPED_MISSES += 1
     inner = scan_fn
     if replicated:
         rep_axes = ((0, 0), (None, 0, 0, 0))
