@@ -561,6 +561,13 @@ class Session:
         for the session's own seed, the exact schedule stream — of the
         un-replicated sweep is preserved.
 
+        Per-lane finals are host ``numpy`` arrays: each sub-sweep (a chunk
+        or a rule group) copies its stacked final params to the host once,
+        one transfer per leaf, and each lane's params are read-only views of
+        that copy, bitwise the lane's slice of the device carry (with
+        ``T <= 0``, every lane gets ``params0`` on the host). Round logs are
+        built for all lanes of a sub-sweep in one pass.
+
         ``lane_chunk`` streams grids through fixed-size cell chunks (at most
         ``lane_chunk`` cells per dispatch, results accumulated host-side in
         caller order — chunking is bitwise-invariant, locked by
@@ -588,6 +595,7 @@ class Session:
         if C == 0:
             return []
         if T <= 0:
+            params = jax.device_get(params)
             return [[(params, [])] * R for _ in range(C)] if replicated \
                 else [(params, []) for _ in range(C)]
 
@@ -701,18 +709,10 @@ class Session:
             with obs.span("repro.wait"):
                 oks.append(np.asarray(ok))  # (C, [R,] b - a)
             a = b
-        with obs.span("repro.results", lanes=C * R):
-            ok_all = np.concatenate(oks, axis=-1)
-            if not replicated:
-                return [(jax.tree.map(lambda l, c=c: l[c], carry[0]),
-                         rt._round_logs(levels, ok_all[c], masks[c],
-                                        cfg.mlmc.j_max))
-                        for c in range(C)]
-            return [[(jax.tree.map(lambda l, c=c, r=r: l[c, r], carry[0]),
-                      rt._round_logs(levels, ok_all[c, r], masks[c, r],
-                                     cfg.mlmc.j_max))
-                     for r in range(R)]
-                    for c in range(C)]
+        with obs.span("repro.results", lanes=C * R) as s:
+            return _lane_results(carry[0], levels,
+                                 np.concatenate(oks, axis=-1), masks,
+                                 cfg.mlmc.j_max, s)
 
     def sweep_halving(self, spec: SweepSpec, T: int, *,
                       objective: Callable[[Any], float],
@@ -807,18 +807,12 @@ class Session:
         def take(tree, idx):
             return jax.tree.map(lambda l: l[jnp.asarray(idx)], tree)
 
-        def cell_out(carry, ok_rows, c_local: int, cell: int):
-            """(params, logs) per replicate for local lane ``c_local``."""
-            if not replicated:
-                p = jax.tree.map(lambda l: l[c_local], carry[0])
-                return [(p, rt._round_logs(levels[:ok_rows.shape[-1]],
-                                           ok_rows[c_local], masks[cell],
-                                           cfg.mlmc.j_max))]
-            return [(jax.tree.map(lambda l, r=r: l[c_local, r], carry[0]),
-                     rt._round_logs(levels[:ok_rows.shape[-1]],
-                                    ok_rows[c_local, r], masks[cell, r],
-                                    cfg.mlmc.j_max))
-                    for r in range(R)]
+        def cell_outs(carry, ok_rows, alive, span):
+            """Per live cell, its (params, logs) per replicate."""
+            outs = _lane_results(carry[0], levels[:ok_rows.shape[-1]],
+                                 ok_rows, masks[np.asarray(alive)],
+                                 cfg.mlmc.j_max, span)
+            return outs if replicated else [[o] for o in outs]
 
         carry = (lanes(self.params0), lanes(self.opt.init(self.params0)))
         alive = list(range(C))  # original cell index per live lane
@@ -847,10 +841,10 @@ class Session:
             if b == T:
                 break
             # ---- prune: mean objective over replicates, lower is better
-            finals = np.array(
-                [[float(objective(p)) for p, _ in
-                  cell_out(carry, ok_all, j, cell)]
-                 for j, cell in enumerate(alive)])
+            with obs.span("repro.results", lanes=len(alive) * R) as s:
+                results = cell_outs(carry, ok_all, alive, s)
+            finals = np.array([[float(objective(p)) for p, _ in cell]
+                               for cell in results])
             scores = np.where(np.isnan(finals), np.inf, finals).mean(axis=1)
             k = max(int(min_cells), int(np.ceil(len(alive) * keep)))
             if n_lanes_mesh > 1:  # keep the lane axis divisible
@@ -862,9 +856,8 @@ class Session:
             if len(keep_local) < len(alive):
                 for j, cell in enumerate(alive):
                     if j not in set(keep_local):
-                        outs[cell] = {
-                            "pruned": True, "rounds_run": b,
-                            "results": cell_out(carry, ok_all, j, cell)}
+                        outs[cell] = {"pruned": True, "rounds_run": b,
+                                      "results": results[j]}
                 carry = (take(carry[0], keep_local),
                          take(carry[1], keep_local))
                 if lane_mode:
@@ -873,12 +866,32 @@ class Session:
                 oks = [o[np.asarray(keep_local)] for o in oks]
                 alive = [alive[j] for j in keep_local]
             a = b
-        with obs.span("repro.results", lanes=len(alive) * R):
-            ok_all = np.concatenate(oks, axis=-1)
-            for j, cell in enumerate(alive):
-                outs[cell] = {"pruned": False, "rounds_run": T,
-                              "results": cell_out(carry, ok_all, j, cell)}
+        with obs.span("repro.results", lanes=len(alive) * R) as s:
+            results = cell_outs(carry, ok_all, alive, s)
+        for cell, res in zip(alive, results):
+            outs[cell] = {"pruned": False, "rounds_run": T, "results": res}
         return outs
+
+
+def _lane_results(params, levels, ok, masks, j_max: int, span) -> list:
+    """Per-lane ``(params, logs)`` of one sub-sweep, nested over the lane
+    dims ``ok.shape[:-1]`` — ``(C,)`` or ``(C, R)`` — as ``sweep`` returns
+    them. The stacked final ``params`` come to the host in ONE copy, one
+    transfer per leaf (``span`` counts the leaves under ``copies``); each
+    lane's params are read-only numpy views of that copy, and the round logs
+    of every lane come from one ``rt._round_logs_lanes`` pass."""
+    leaves, treedef = jax.tree.flatten(jax.device_get(params))
+    span.add("copies", len(leaves))
+    for leaf in leaves:
+        leaf.flags.writeable = False  # views of it inherit the flag
+    lead = ok.shape[:-1]
+    flat = list(zip((treedef.unflatten([leaf[i] for leaf in leaves])
+                     for i in np.ndindex(*lead)),
+                    rt._round_logs_lanes(levels, ok, masks, j_max)))
+    if len(lead) == 2:
+        R = lead[1]
+        return [flat[c:c + R] for c in range(0, len(flat), R)]
+    return flat
 
 
 def _task_sampler_factory(task, m: int):

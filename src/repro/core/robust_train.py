@@ -397,17 +397,30 @@ def _level_plan(cfg: DynaBROConfig, rng: np.random.Generator, T: int):
     return levels, ns, n_max
 
 
+def _round_logs_lanes(levels, ok, masks, j_max: int) -> list:
+    """Per-round RoundLog lists for every lane of a sweep, in one pass: the
+    level plan ``levels`` (T,), the scanned fail-safe flags ``ok`` (*L, T)
+    and the mask schedules ``masks`` (*L, T', n_max, m), T' >= T (rounds past
+    T are not read). Returns one list per lane, the lanes of the leading
+    dims L in row-major order. The compiled drivers' side of the
+    ``mlmc.round_cost`` cost-accounting contract (beyond-cap rounds,
+    j > j_max, cost 1: the correction is dropped)."""
+    T = len(levels)
+    ok = np.asarray(ok, bool)[..., :T]
+    lanes = math.prod(ok.shape[:-1])
+    js = [int(j) for j in levels]
+    costs = [round_cost(j, j_max) for j in js]
+    n_byz = np.asarray(masks)[..., :T, 0, :].sum(-1, dtype=np.int64)
+    return [list(map(RoundLog, js, o, n, costs))
+            for o, n in zip(ok.reshape(lanes, T).tolist(),
+                            n_byz.reshape(lanes, T).tolist())]
+
+
 def _round_logs(levels, ok, masks, j_max: int) -> list:
-    """Per-round RoundLog list from the level plan, the scanned fail-safe
-    flags (T,) and the (T, n_max, m) mask schedule — the compiled drivers'
-    side of the ``mlmc.round_cost`` cost-accounting contract (beyond-cap
-    rounds, j > j_max, cost 1: the correction is dropped)."""
-    logs = []
-    for t in range(len(levels)):
-        j = int(levels[t])
-        logs.append(RoundLog(j, bool(ok[t]), int(masks[t, 0].sum()),
-                             round_cost(j, j_max)))
-    return logs
+    """``_round_logs_lanes`` for one lane: ``ok`` (T,), ``masks``
+    (T, n_max, m)."""
+    return _round_logs_lanes(levels, np.asarray(ok)[None],
+                             np.asarray(masks)[None], j_max)[0]
 
 
 def _mask_schedule(switcher: Switcher, T: int, n_max: int,

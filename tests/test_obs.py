@@ -211,6 +211,31 @@ def test_sweep_spans_match_the_profile(tmp_path, monkeypatch):
                 assert counts[k] == v, (name, k)
 
 
+def _two_leaf_grad(params, unit_key):
+    g = TASK.grad_fn({"x": params["x"]}, unit_key)
+    return {"x": g["x"], "b": 0.5 * params["b"]}
+
+
+def test_results_copy_the_carry_once_per_sub_sweep(tmp_path):
+    """``repro.results`` counts one host copy per parameter leaf, once per
+    sub-sweep (two chunks of two rule groups), not once per lane."""
+    params0 = {"x": TASK.params0["x"], "b": jnp.arange(3.0)}
+    sess = Session(_cfg(), grad_fn=_two_leaf_grad, params0=params0,
+                   opt=sgd(2e-2), m=M, sample_batches=TASK.make_sampler(M),
+                   sampler_factory=_task_sampler_factory(TASK, M), seed=0)
+    with jax.profiler.trace(str(tmp_path)):
+        outs = sess.sweep(_two_rule_spec(scan_fn=None), T, lane_chunk=2)
+    results = [r for r in obs.records() if r.name == "repro.results"]
+    assert len(results) == 4
+    n_leaves = len(jax.tree.leaves(params0))
+    assert n_leaves == 2
+    assert [r.counts["copies"] for r in results] == [n_leaves] * 4
+    assert [r.counts["lanes"] for r in results] == [2] * 4
+    assert all(type(leaf) is np.ndarray and not leaf.flags.writeable
+               for cell in outs for p, _ in cell
+               for leaf in jax.tree.leaves(p))
+
+
 def test_run_spans(tmp_path):
     evals = []
     sess = _session(switcher=get_switcher("periodic", M, n_byz=3, K=4))

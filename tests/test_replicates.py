@@ -16,6 +16,9 @@ Contracts locked here:
   with forced host devices, same pattern as test_scan_driver_sharded.py).
 - **Halving**: successive-halving survivors are bitwise a plain sweep of
   the surviving subset; pruned cells report their state at the pruning rung.
+- **Host results**: every sweep's per-lane finals are read-only host numpy
+  views, bitwise the eager per-lane slices of the sub-sweep's stacked carry;
+  the batched round-log builder equals the single-lane one lane by lane.
 - **Reporting**: run_matrix(driver="vmap") rows carry mean/std/stderr and
   n_seeds; format_table renders the error bar only for n_seeds >= 2; the
   per-cell drivers reject the replicate kwargs.
@@ -25,17 +28,20 @@ import subprocess
 import sys
 import textwrap
 
+import jax
 import numpy as np
 import pytest
 
+from repro.api import session as session_mod
 from repro.api.session import Session, _task_sampler_factory
 from repro.api.specs import SweepSpec
-from repro.core.mlmc import MLMCConfig
+from repro.core import robust_train as rt
+from repro.core.mlmc import MLMCConfig, round_cost
 from repro.core.robust_train import DynaBROConfig, make_dynabro_scan_fn
 from repro.core.scenarios import (
     format_table, make_quadratic_task, run_matrix, scenario_grid,
 )
-from repro.core.switching import get_switcher
+from repro.core.switching import Switcher, get_switcher
 from repro.launch.mesh import make_lane_mesh, make_mesh
 from repro.optim.optimizers import sgd
 
@@ -336,6 +342,127 @@ def test_halving_validation():
     with pytest.raises(ValueError, match="mapping"):
         sess.sweep_halving(SweepSpec(switchers=SWS, scan_fn=fns), T,
                            objective=TASK.objective)
+
+
+# -------------------------------------------------------------- host results
+
+HALVING_SWS = tuple(("periodic", dict(n_byz=b, K=k))
+                    for b, k in ((3, 4), (3, 8), (3, 16), (5, 4), (5, 8),
+                                 (5, 16)))
+
+# case -> (sweep call, its finals in caller order)
+HOST_CASES = {
+    "plain": lambda s: [p for p, _ in s.sweep(SweepSpec(switchers=SWS), T)],
+    "replicated": lambda s: [p for c in s.sweep(
+        SweepSpec(switchers=SWS, seeds=(0, 1)), T) for p, _ in c],
+    "lane_chunk": lambda s: [p for c in s.sweep(
+        SweepSpec(switchers=SWS, seeds=(0, 1)), T, lane_chunk=2)
+        for p, _ in c],
+    "rule_groups": lambda s: [p for c in s.sweep(
+        SweepSpec(switchers=SWS + SWS[:1],
+                  aggregators=("cwmed", "cwtm", "cwmed", "cwtm"),
+                  seeds=(0, 1)), T, lane_chunk=3) for p, _ in c],
+    "halving": lambda s: [p for o in s.sweep_halving(
+        SweepSpec(switchers=HALVING_SWS, seeds=(0, 1)), T,
+        objective=TASK.objective, keep=0.5) for p, _ in o["results"]],
+    "halving_plain": lambda s: [p for o in s.sweep_halving(
+        SweepSpec(switchers=HALVING_SWS), T, objective=TASK.objective,
+        keep=0.5) for p, _ in o["results"]],
+}
+
+
+@pytest.mark.parametrize("case", sorted(HOST_CASES))
+def test_sweep_finals_are_readonly_host_views_of_the_carry(case,
+                                                           monkeypatch):
+    """Each sub-sweep's finals are read-only numpy views of one host copy,
+    bitwise the eager ``l[c]`` / ``l[c, r]`` device slices the per-lane
+    scatter used to take, lane for lane."""
+    calls = []
+    real = session_mod._lane_results
+
+    def recording(params, levels, ok, masks, j_max, span):
+        out = real(params, levels, ok, masks, j_max, span)
+        calls.append((params, ok.shape[:-1], out))
+        return out
+
+    monkeypatch.setattr(session_mod, "_lane_results", recording)
+    finals = HOST_CASES[case](_sess())
+    views = {}
+    for params, lead, out in calls:
+        for idx in np.ndindex(*lead):
+            lane = out[idx[0]][idx[1]] if len(lead) == 2 else out[idx[0]]
+            eager = jax.tree.map(lambda l: l[idx], params)
+            for got, want in zip(jax.tree.leaves(lane[0]),
+                                 jax.tree.leaves(eager), strict=True):
+                assert type(got) is np.ndarray
+                assert not got.flags.writeable
+                assert got.dtype == want.dtype
+                np.testing.assert_array_equal(got, np.asarray(want))
+            views[id(lane[0])] = lane[0]
+    # every lane the sweep hands back is one of those views (halving also
+    # copies every live lane at its rung)
+    if not case.startswith("halving"):
+        assert len(finals) == len(views)
+    for p in finals:
+        assert views.get(id(p)) is p
+        with pytest.raises(ValueError):
+            p["x"][0] = 0.0
+
+
+class _Flipper(Switcher):
+    """A stateful within-round switcher: each call flips which half of the
+    workers is Byzantine, so only the replayed call sequence is exact."""
+
+    def __init__(self, m):
+        super().__init__(m)
+        self.calls = 0
+
+    def mask(self, t):
+        return np.zeros(self.m, bool)
+
+    def within_round(self, t, k):
+        self.calls += 1
+        mk = np.zeros(self.m, bool)
+        mk[: self.m // 2] = self.calls % 2 == 1
+        mk[-1] = (t + k) % 3 == 0
+        return mk
+
+
+def _loop_logs(levels, ok, masks, j_max):
+    """The per-round loop the batched builder replaced."""
+    return [rt.RoundLog(int(levels[t]), bool(ok[t]), int(masks[t, 0].sum()),
+                        round_cost(int(levels[t]), j_max))
+            for t in range(len(levels))]
+
+
+@pytest.mark.parametrize("lead,within,seed", [
+    ((5,), False, 0), ((3, 4), False, 1), ((4,), True, 2),
+    ((2, 3), True, 3), ((1,), False, 4)])
+def test_round_logs_lanes_equal_single_lane(lead, within, seed):
+    rng = np.random.default_rng(seed)
+    j_max, n_max, m, T_ = 3, 8, 6, 40
+    # levels up to j_max + 2: beyond-cap rounds cost 1
+    levels = rng.integers(0, j_max + 3, T_).astype(np.int32)
+    assert (levels > j_max).any()
+    ok = rng.random(lead + (T_,)) < 0.7
+    if within:
+        ns = np.where(levels <= j_max, 2 ** levels.astype(np.int64), 1)
+        masks = np.stack([rt._mask_schedule(_Flipper(m), T_ + 3, n_max,
+                                            np.append(ns, [1, 1, 1]))
+                          for _ in range(int(np.prod(lead)))])
+        masks = masks.reshape(lead + masks.shape[1:])
+    else:
+        masks = rng.random(lead + (T_ + 3, n_max, m)) < 0.3
+    batched = rt._round_logs_lanes(levels, ok, masks, j_max)
+    assert len(batched) == int(np.prod(lead))
+    for logs, idx in zip(batched, np.ndindex(*lead), strict=True):
+        single = rt._round_logs(levels, ok[idx], masks[idx], j_max)
+        assert logs == single == _loop_logs(levels, ok[idx], masks[idx],
+                                            j_max)
+        assert all(type(lg.level) is int and type(lg.failsafe_ok) is bool
+                   and type(lg.n_byz) is int and type(lg.cost) is int
+                   for lg in logs)
+    assert any(lg.cost == 1 and lg.level > j_max for lg in batched[0])
 
 
 # ----------------------------------------------------- reporting / run_matrix
