@@ -10,9 +10,11 @@ One process: the cell's programs compile once, then per seed the program
 runs its checked steps (or its grid) and the reference follows; on the
 first ``--control`` seeds the control (the reference one precision step
 down) and on the first ``--faults`` seeds each planted fault is compared
-with the reference the same way. Prints one JSON line per reading and a
-summary: per number the largest program reading (the lower reading) and
-the smallest control and fault readings (candidates for the upper one).
+with the reference the same way (for a cell of several workers, one fault
+leaves their exchange out). Prints one JSON line per reading, with each
+device's bytes in use at that reference run's high point, and a summary:
+per number the largest program reading (the lower reading) and the
+smallest control and fault readings (candidates for the upper one).
 """
 from __future__ import annotations
 
@@ -59,9 +61,11 @@ def main(argv=None) -> int:
           flush=True)
     found = {}
 
-    def note(kind: str, seed: int, checks, seconds: float):
+    def note(kind: str, seed: int, checks, seconds: float, ref: dict):
         line = {"kind": kind, "seed": seed, "seconds": round(seconds, 2),
                 **{n: v for n, v, _ in checks}}
+        if "memory" in ref:  # the reference run's bytes per device
+            line["memory"] = ref["memory"]
         print(json.dumps(line), flush=True)
         for n, v, _ in checks:
             found.setdefault(kind, {}).setdefault(n, []).append(v)
@@ -74,18 +78,18 @@ def main(argv=None) -> int:
         driver.release()
         ref = driver.reference()
         note("program", seed, compare(prog, ref, run.limits),
-             time.perf_counter() - t)
+             time.perf_counter() - t, ref)
         if i < args.control:
             t = time.perf_counter()
             ctl = driver.reference(**driver.CONTROL)
             note("control", seed, compare(ctl, ref, run.limits),
-                time.perf_counter() - t)
+                 time.perf_counter() - t, ctl)
         if i < args.faults:
             for name, kw in driver.FAULTS.items():
                 t = time.perf_counter()
                 f = driver.reference(**kw)
                 note(f"fault:{name}", seed, compare(f, ref, run.limits),
-                    time.perf_counter() - t)
+                     time.perf_counter() - t, f)
     summary = {kind: {n: (max(v) if kind == "program" else min(v))
                       for n, v in nums.items()}
                for kind, nums in found.items()}

@@ -13,6 +13,7 @@ the parameters' change are what the reference is compared with.
 from __future__ import annotations
 
 import math
+import sys
 import time
 
 import numpy as np
@@ -258,8 +259,15 @@ class Driver:
     # options (calibrate.py reads them over many seeds)
     CONTROL = {"quant": "fp8"}
 
-    FAULTS = {"half_batch": {"fault": "half_batch"},
-              "state_unchanged": {"fault": "state_unchanged"}}
+    @property
+    def FAULTS(self) -> dict:
+        """Half of each unit's rows, the state unchanged and, where there
+        are workers to exchange with, the exchange left out."""
+        faults = {"half_batch": {"fault": "half_batch"},
+                  "state_unchanged": {"fault": "state_unchanged"}}
+        if self.m > 1:
+            faults["no_exchange"] = {"fault": "no_exchange"}
+        return faults
 
     def program_readings(self):
         return self.readings
@@ -267,7 +275,10 @@ class Driver:
     def check(self):
         """The reference through the checked steps, compared with the
         program's readings: [(name, value, limit)]."""
-        return compare(self.readings, self.reference(), self.run.limits)
+        ref = self.reference()
+        print(f"[{self.run.name}] reference bytes in use at its high point, "
+              f"per device: {ref['memory']}", file=sys.stderr, flush=True)
+        return compare(self.readings, ref, self.run.limits)
 
     def reference(self, **kw):
         import jax

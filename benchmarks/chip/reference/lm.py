@@ -15,7 +15,10 @@ scale), the step below the bfloat16 the configuration states.
 
 Nothing here keeps a full batch of activations: each unit of rows is one
 forward and backward, with every layer rematerialised, and the nested
-level means are sums of unit gradients.
+level means are sums of unit gradients. Across several devices nothing
+is gathered on one: each leaf's coordinates are cut into one block per
+device (``Layout``), and a worker's whole replica is the only full-size
+copy of a leaf a device holds beside that worker's own level sums.
 """
 from __future__ import annotations
 
@@ -166,16 +169,26 @@ class Reference:
     def add_unit(self, acc, params, ids):
         """``acc`` plus the gradient of the mean loss over one unit of rows
         ((rows, S + 1) ids), taken ``ROWS_PER_PASS`` rows at a time; returns
-        (the unit's mean loss, the new sum). ``acc`` is consumed."""
+        (the passes' losses, still on the device, and the new sum). ``acc``
+        is consumed. Nothing here waits for the device, so workers on
+        different devices run side by side."""
         parts = max(ids.shape[0] // ROWS_PER_PASS, 1)
         rows = ids.shape[0] // parts
-        loss = 0.0
+        losses = []
         for i in range(parts):
             lval, acc = self._accumulate(acc, params,
                                          ids[i * rows:(i + 1) * rows],
                                          jnp.float32(1.0 / parts))
-            loss += float(lval) / parts
-        return loss, acc
+            losses.append(lval)
+        return losses, acc
+
+
+def unit_loss(losses) -> float:
+    """A unit's mean loss from the losses of its passes."""
+    loss = 0.0
+    for lval in losses:
+        loss += float(lval) / len(losses)
+    return loss
 
 
 # ---------------------------------------------------------------- training
@@ -202,13 +215,79 @@ def failsafe_coeff(mlmc: dict, m: int) -> float:
     return (1.0 + math.sqrt(2.0)) * c_e * C * mlmc["V"]
 
 
+class Layout:
+    """Where each leaf's coordinates live. With n devices, leaf k is cut
+    along ``axis[k]``, its first axis that n divides, into n even blocks,
+    block d on ``devices[d]``; a leaf that no axis divides, and every leaf
+    on one device, is one block on the first device. A tree in this layout
+    is {leaf: [blocks]}."""
+
+    def __init__(self, shapes: dict, devices):
+        self.devices, n = list(devices), len(devices)
+        self.axis = {k: next((a for a, s in enumerate(sh) if s % n == 0),
+                             None) if n > 1 else None
+                     for k, sh in shapes.items()}
+
+    def owners(self, k: str) -> list:
+        return self.devices if self.axis[k] is not None else self.devices[:1]
+
+    def shares(self, k: str, x) -> list:
+        """``x``, a whole leaf k on any device, cut into its blocks where it
+        is; the blocks are not moved."""
+        if self.axis[k] is None:
+            return [x]
+        return jnp.split(x, len(self.devices), axis=self.axis[k])
+
+    def scatter(self, tree: dict) -> dict:
+        """Whole leaves -> blocks on their owners; each whole leaf is
+        dropped once it is cut."""
+        out = {}
+        for k in list(tree):
+            out[k] = [jax.device_put(b, d) for b, d in
+                      zip(self.shares(k, tree.pop(k)), self.owners(k))]
+        return out
+
+    def whole(self, k: str, blocks: list, device):
+        """Leaf k assembled from its blocks on ``device``."""
+        if self.axis[k] is None:
+            return jax.device_put(blocks[0], device)
+        return jnp.concatenate([jax.device_put(b, device) for b in blocks],
+                               self.axis[k])
+
+    def host(self, k: str, blocks: list) -> np.ndarray:
+        if self.axis[k] is None:
+            return np.asarray(blocks[0])
+        return np.concatenate([np.asarray(b) for b in blocks], self.axis[k])
+
+
+def _sq(blocks: list):
+    """A leaf's sum of squares: each device's partial sum in float32, added
+    in float32 on the first block's device."""
+    parts = [jnp.sum(jnp.square(b)) for b in blocks]
+    dev = next(iter(parts[0].devices()))
+    total = parts[0]
+    for p in parts[1:]:
+        total = total + jax.device_put(p, dev)
+    return total
+
+
 def _tree_norm(tree) -> float:
-    return math.sqrt(sum(float(jnp.sum(jnp.square(l))) for l in tree.values()))
+    return math.sqrt(sum(float(_sq(bl)) for bl in tree.values()))
 
 
 def leaf_norms(tree) -> dict:
-    return {k: float(jnp.sqrt(jnp.sum(jnp.square(v.astype(jnp.float32)))))
-            for k, v in tree.items()}
+    return {k: float(jnp.sqrt(_sq(bl))) for k, bl in tree.items()}
+
+
+def memory_in_use(devices) -> dict:
+    """Bytes each device holds now, where the backend reports it (the
+    caller waits for the work it wants counted)."""
+    out = {}
+    for d in devices:
+        stats = d.memory_stats() or {}
+        if "bytes_in_use" in stats:
+            out[str(d.id)] = int(stats["bytes_in_use"])
+    return out
 
 
 class Trainer:
@@ -219,16 +298,28 @@ class Trainer:
     ``delta``, ``attack`` (sign_flip or none), ``mlmc`` {T, V, kappa,
     cap} and ``optimizer`` {kind: adam, lr, b1, b2, eps}. ``fault``
     plants one of the faults the comparison must catch:
-    ``"half_batch"`` (each unit's gradient from its first half of rows)
-    or ``"state_unchanged"`` (the steps leave the parameters as they
-    were)."""
+    ``"half_batch"`` (each unit's gradient from its first half of rows),
+    ``"state_unchanged"`` (the steps leave the parameters as they were) or
+    ``"no_exchange"`` (the level sums never leave their worker's device:
+    each block is aggregated over the workers on its own device alone).
+
+    Worker w computes its gradients on ``devices[w % n]`` from a whole
+    float32 replica. Every other value lives in blocks (``Layout``): the
+    workers' level sums are cut into blocks, each sent to the device that
+    owns it, and the attack, the trimmed mean, the MLMC difference and its
+    norm, Adam's moments and the update run there, block by block; each
+    step's replicas are assembled from the blocks. On one device the
+    arithmetic is that of a single whole-leaf program. ``memory`` holds
+    each device's largest ``bytes_in_use`` between the phases of a step.
+    """
 
     def __init__(self, model: dict, train: dict, *, quant: str = "",
                  fault: str = "", devices=None):
         self.model, self.train, self.fault = model, train, fault
         self.m = int(train["workers"])
-        self.devices = devices or jax.devices()[:1]
+        self.devices = list(devices or jax.devices()[:1])
         self.ref = Reference(model, quant=quant)
+        self.memory = {}
         opt = train["optimizer"]
         if train["aggregator"] != "cwtm" or opt["kind"] != "adam":
             raise ValueError("the reference runs cwtm and adam")
@@ -236,104 +327,139 @@ class Trainer:
             float(opt["lr"]), float(opt["b1"]), float(opt["b2"]),
             float(opt["eps"]))
 
-    def _worker_units(self, replicas, ids, w: int, units: int):
+    def _note_memory(self):
+        for d, b in memory_in_use(self.devices).items():
+            self.memory[d] = max(self.memory.get(d, 0), b)
+
+    def _worker_units(self, params, ids, w: int, units: int):
         """Worker w's sums of unit gradients over the nested prefixes of
-        1, units/2 and units units, and its mean loss over all units. The
-        worker runs on device w mod the devices given."""
+        1, units/2 and units units, on its device from its replica
+        ``params``, and its units' pass losses."""
         dev = self.devices[w % len(self.devices)]
-        params = replicas[w % len(replicas)]
         rows = ids.shape[0] // self.m  # this worker's local rows
         per_unit = rows // units
         local = ids[w * rows:(w + 1) * rows]
         acc = jax.tree.map(jnp.zeros_like, params)
-        sums, loss = {}, 0.0
+        sums, losses = {}, []
         for u in range(units):
             block = jax.device_put(local[u * per_unit:(u + 1) * per_unit],
                                    dev)
             if self.fault == "half_batch":
                 block = block[:max(per_unit // 2, 1)]
-            lval, acc = self.ref.add_unit(acc, params, block)
-            loss += lval
+            lvals, acc = self.ref.add_unit(acc, params, block)
+            losses.append(lvals)
             if u + 1 in (1, units // 2) and u + 1 < units:
                 sums[u + 1] = jax.tree.map(jnp.copy, acc)
         sums[units] = acc
-        return sums, loss / units
+        return sums, losses
 
-    def _aggregate(self, per_worker, n: int, mask):
-        """Level n: each worker's mean over n units, the attack, then the
-        coordinate-wise trimmed mean over workers, leaf by leaf on the
-        first device. Frees the workers' level-n sums as it goes."""
+    def _aggregate(self, per_worker, n: int, mask, lay: Layout):
+        """Level n, in blocks: each worker's level-n sum of a leaf is cut
+        into blocks, each block sent to its owner, where the m values are
+        divided by n, attacked and trimmed. Frees the workers' sums leaf by
+        leaf as they leave."""
         trim = trim_count(float(self.train["delta"]), self.m)
+        n_dev = len(self.devices)
+        if self.train["attack"] not in ("sign_flip", "none"):
+            raise ValueError(f"attack {self.train['attack']!r}")
         out = {}
         for k in list(per_worker[0][n]):
-            stack = jnp.stack([jax.device_put(pw[n].pop(k), self.devices[0])
-                               for pw in per_worker]) / n
-            if self.train["attack"] == "sign_flip":
-                sign = jnp.where(jnp.asarray(mask), -1.0, 1.0)
-                stack = stack * sign.reshape((-1,) + (1,) * (stack.ndim - 1))
-            elif self.train["attack"] != "none":
-                raise ValueError(f"attack {self.train['attack']!r}")
-            out[k] = trimmed_mean(stack, trim)
+            shares = [lay.shares(k, pw[n].pop(k)) for pw in per_worker]
+            blocks = []
+            for d, dev in enumerate(lay.owners(k)):
+                ws = list(range(self.m))
+                if self.fault == "no_exchange":
+                    here = [w for w in ws if w % n_dev == d]
+                    ws = [here[i % len(here)] for i in range(self.m)]
+                stack = jnp.stack([jax.device_put(shares[w][d], dev)
+                                   for w in ws]) / n
+                if self.train["attack"] == "sign_flip":
+                    sign = jax.device_put(jnp.where(
+                        jnp.asarray(np.asarray(mask)[ws]), -1.0, 1.0), dev)
+                    stack = stack * sign.reshape((-1,) + (1,) *
+                                                 (stack.ndim - 1))
+                blocks.append(trimmed_mean(stack, trim))
+            del shares
+            out[k] = blocks
         return out
 
     def run(self, make_params0, step_ids, step_masks, step_levels):
-        """Steps from the weights ``make_params0()`` gives (called again at
-        the end, rather than kept) through the given per-step (ids, mask,
-        level). Returns
+        """Steps from the weights ``make_params0()`` gives (whole float32
+        leaves on any device; called again at the end, rather than kept)
+        through the given per-step (ids, mask, level). Returns
         {"loss": [...], "grad": leaf norms of the first step's gradient,
         "grad_full": that gradient on the host,
         "change": leaf norms of the parameters' change after the last
-        step, "failsafe_ok" and "corr_norm" per MLMC step}."""
+        step, "failsafe_ok" and "corr_norm" per MLMC step, "memory": each
+        device's largest bytes in use}."""
         mlmc = self.train["mlmc"]
         cap, coeff = int(mlmc["cap"]), failsafe_coeff(mlmc, self.m)
         params = make_params0()
-        replicas = [params] + [jax.device_put(params, d)
-                               for d in self.devices[1:]]
-        mom = {k: jnp.zeros_like(v) for k, v in params.items()}
-        vel = {k: jnp.zeros_like(v) for k, v in params.items()}
+        lay = Layout({k: v.shape for k, v in params.items()}, self.devices)
+        params = lay.scatter(params)
+        mom = {k: [jnp.zeros_like(b) for b in bl] for k, bl in params.items()}
+        vel = {k: [jnp.zeros_like(b) for b in bl] for k, bl in params.items()}
         out = {"loss": [], "failsafe_ok": [], "corr_norm": []}
+        self.memory = {}
         for t, (ids, mask, J) in enumerate(zip(step_ids, step_masks,
                                                step_levels)):
             units = 2 ** J if 1 <= J <= cap else 1
-            per_worker, losses = [], []
+            per_worker, losses, replicas = [], [], {}
             for w in range(self.m):
-                sums, lw = self._worker_units(replicas, ids, w, units)
+                dev = self.devices[w % len(self.devices)]
+                if dev not in replicas:
+                    replicas[dev] = {k: lay.whole(k, bl, dev)
+                                     for k, bl in params.items()}
+                sums, lw = self._worker_units(replicas[dev], ids, w, units)
                 per_worker.append(sums)
                 losses.append(lw)
-            agg = {n: self._aggregate(per_worker, n, mask)
+            # the most that lives at once: a replica and a worker's level
+            # sums on every device, beside the blocks
+            jax.block_until_ready(per_worker)
+            self._note_memory()
+            del replicas
+            agg = {n: self._aggregate(per_worker, n, mask, lay)
                    for n in sorted({1, max(units // 2, 1), units})}
             del per_worker
             g0 = agg[1]
             if 1 <= J <= cap:
-                diff = {k: agg[units][k] - agg[units // 2][k] for k in g0}
+                diff = {k: [a - b for a, b in zip(agg[units][k],
+                                                  agg[units // 2][k])]
+                        for k in g0}
                 dn = _tree_norm(diff)
                 ok = dn <= coeff / math.sqrt(2.0 ** J)
-                g = {k: g0[k] + (2.0 ** J if ok else 0.0) * diff[k]
-                     for k in g0}
+                g = {k: [a + (2.0 ** J if ok else 0.0) * b
+                         for a, b in zip(g0[k], diff[k])] for k in g0}
                 out["failsafe_ok"].append(bool(ok))
                 out["corr_norm"].append(dn)
             else:
                 g = g0
             del agg
-            out["loss"].append(float(np.mean(losses)))
+            self._note_memory()
+            out["loss"].append(float(np.mean([
+                sum(unit_loss(u) for u in lw) / units for lw in losses])))
             if t == 0:
                 out["grad"] = leaf_norms(g)
-                out["grad_full"] = {k: np.asarray(v) for k, v in g.items()}
+                out["grad_full"] = {k: lay.host(k, bl) for k, bl in g.items()}
             step = t + 1
-            mom = {k: self.b1 * mom[k] + (1 - self.b1) * g[k] for k in g}
-            vel = {k: self.b2 * vel[k] + (1 - self.b2) * g[k] ** 2
-                   for k in g}
+            mom = {k: [self.b1 * a + (1 - self.b1) * b
+                       for a, b in zip(mom[k], g[k])] for k in g}
+            vel = {k: [self.b2 * a + (1 - self.b2) * b ** 2
+                       for a, b in zip(vel[k], g[k])] for k in g}
             for k in params:
                 if self.fault == "state_unchanged":
                     break
-                mh = mom[k] / (1 - self.b1 ** step)
-                vh = vel[k] / (1 - self.b2 ** step)
-                params[k] = params[k] - self.lr * mh / (jnp.sqrt(vh)
-                                                        + self.eps_adam)
-            replicas = [params] + [jax.device_put(params, d)
-                                   for d in self.devices[1:]]
-        del mom, vel, replicas
-        params0 = make_params0()
-        out["change"] = leaf_norms({k: params[k] - params0[k]
+                upd = []
+                for p, mk, vk in zip(params[k], mom[k], vel[k]):
+                    mh = mk / (1 - self.b1 ** step)
+                    vh = vk / (1 - self.b2 ** step)
+                    upd.append(p - self.lr * mh / (jnp.sqrt(vh)
+                                                   + self.eps_adam))
+                params[k] = upd
+        del mom, vel
+        params0 = lay.scatter(make_params0())
+        out["change"] = leaf_norms({k: [a - b for a, b in zip(params[k],
+                                                              params0[k])]
                                     for k in params})
+        out["memory"] = dict(self.memory)
         return out

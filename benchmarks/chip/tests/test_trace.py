@@ -4,6 +4,7 @@
 """
 import os
 import sys
+import types
 
 import pytest
 
@@ -22,9 +23,9 @@ def test_union_and_subtract():
     assert trace.subtract([[0, 10]], []) == [[0, 10]]
 
 
-def test_reduce_hand_made():
+def _hand_made():
     ns = 10 ** 9  # whole seconds, to read the results plainly
-    ext = {
+    return {
         "devices": {0: [["dot", 5 * ns, 30 * ns, "compute"],
                         ["all-to-all", 25 * ns, 40 * ns, "collective"],
                         ["cwtm", 50 * ns, 60 * ns, "custom"],
@@ -33,7 +34,10 @@ def test_reduce_hand_made():
         "spans": [["window", 0, 100 * ns], ["place", 0, 10 * ns],
                   ["dispatch", 10 * ns, 12 * ns], ["wait", 12 * ns, 100 * ns]],
     }
-    r = trace.reduce(ext, n_devices=1)
+
+
+def test_reduce_hand_made():
+    r = trace.reduce(_hand_made(), n_devices=1)
     near = pytest.approx
     assert r["window_s"] == near(100)
     assert r["busy_s"] == r["busy_s_dev0"] == near(55)  # [5,40] + [50,70]
@@ -65,6 +69,25 @@ def test_reduce_nested():
     assert [n for n, _ in r["top_ops"]] == ["fusion.2", "custom-call.4",
                                             "all-gather.3"]
     assert r["top_gaps"] == [["wait", near(10)]]
+
+
+def collective_exposed(reduced):
+    """``collective_exposed.train`` as the harness reads it."""
+    from benchmarks.chip import run as bench_run
+
+    return bench_run.load_metric("collective_exposed.train").read(
+        types.SimpleNamespace(trace=reduced))
+
+
+def test_collective_exposed_hand_made():
+    """10 of the window's 100 s hold the all-to-all alone; without a
+    collective the metric reads nothing, never 0."""
+    ext = _hand_made()
+    assert collective_exposed(trace.reduce(ext, n_devices=1)) == \
+        pytest.approx(10.0)
+    ext["devices"][0] = [op for op in ext["devices"][0]
+                         if op[3] != "collective"]
+    assert collective_exposed(trace.reduce(ext, n_devices=1)) is None
 
 
 def test_short_name():
